@@ -7,4 +7,4 @@ import sys
 
 raise SystemExit(subprocess.call(
     [sys.executable, "-m", "repro.launch.serve", "--arch", "mistral-nemo-12b",
-     "--requests", "4", "--max-new", "12"]))
+     "--smoke", "--requests", "4", "--max-new", "12"]))

@@ -35,6 +35,10 @@ _DTYPE_BYTES = {
 }
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
+# ``%name = TYPE opcode(`` — TYPE is an array type or a parenthesized tuple
+_DEF_RE = re.compile(r"\s*(?:ROOT\s+)?%?([\w\.\-]+)\s*=\s*(.*?)\s[a-z][\w\-]*\(")
+# ``name: TYPE`` entries of a computation header's parameter list
+_PARAM_RE = re.compile(r"([\w\.\-]+):\s*(\w+\[[\d,]*\])")
 _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
 
@@ -88,6 +92,17 @@ class CollectiveOp:
         return float(self.operand_bytes)  # collective-permute
 
 
+def _operand_bytes(args: str, types: dict[str, str]) -> int:
+    """Bytes of a collective's operand list: typed operands
+    (``f32[4] %x``) count directly, bare names (``%x``) through their
+    definition's type."""
+    typed = shape_bytes(args)
+    if typed:
+        return typed
+    return sum(shape_bytes(types.get(a.strip().lstrip("%"), ""))
+               for a in args.split(","))
+
+
 def _parse_groups(attr: str, n_devices: int, pod_size: int):
     """replica_groups / source_target_pairs -> (group_size, n_groups, is_dcn)."""
     m = re.search(r"source_target_pairs=\{(\{[\d,\{\}\s]*\})\}", attr)
@@ -125,9 +140,14 @@ def parse_collectives(hlo_text: str, n_devices: int,
                       pod_size: int = 256) -> list[CollectiveOp]:
     """All collective ops with trip-count-aware counts."""
     # split into computations
-    comp_re = re.compile(r"^(?:ENTRY\s+)?%?([\w\.\-]+)\s*\([^)]*\)\s*->.*?\{",
+    comp_re = re.compile(r"^(?:ENTRY\s+)?%?([\w\.\-]+)\s*\(([^)]*)\)\s*->.*?\{",
                          re.M)
     comps: dict[str, list[str]] = {}
+    # per computation: value name -> its type text.  The compiled text
+    # prints operands by name only (``all-reduce(%param.1)``), so operand
+    # bytes come from the operand's definition: an instruction's result
+    # type or a parameter type in the computation header.
+    types: dict[str, dict[str, str]] = {}
     entry = None
     name = None
     for line in hlo_text.splitlines():
@@ -135,11 +155,15 @@ def parse_collectives(hlo_text: str, n_devices: int,
         if m:
             name = m.group(1)
             comps[name] = []
+            types[name] = dict(_PARAM_RE.findall(m.group(2)))
             if line.startswith("ENTRY"):
                 entry = name
             continue
         if name is not None:
             comps[name].append(line)
+            d = _DEF_RE.match(line)
+            if d:
+                types[name][d.group(1)] = d.group(2)
 
     # per computation: collectives and calls (while bodies, calls, conds)
     ops: dict[str, list[CollectiveOp]] = {c: [] for c in comps}
@@ -171,7 +195,8 @@ def parse_collectives(hlo_text: str, n_devices: int,
                                 else sum(shapes)) if shapes else 0
                 args = re.search(r"\((.*?)\)", res[1][head_m.end() - 1:]
                                  if head_m else res[1])
-                operand_bytes = shape_bytes(args.group(1)) if args else 0
+                operand_bytes = _operand_bytes(args.group(1), types[cname]) \
+                    if args else 0
                 gs, ng, dcn = _parse_groups(ln, n_devices, pod_size)
                 ops[cname].append(CollectiveOp(kind, cname, operand_bytes,
                                                result_bytes, gs, ng, dcn))

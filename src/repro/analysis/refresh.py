@@ -11,6 +11,7 @@ import json
 import sys
 
 from repro.analysis import roofline as rf
+from repro.analysis.peaks import peaks
 from repro.configs import all_archs
 from repro.configs.base import SHAPES
 
@@ -23,12 +24,13 @@ def refresh_record(d: dict) -> dict:
     d["hlo_flops_global"] = d["flops_per_device"] * d["n_chips"]
     d["useful_ratio"] = mf / d["hlo_flops_global"] if d["hlo_flops_global"] else 0
     d["bytes_per_device"] = rf.analytic_memory_bytes(cfg, shape, d["n_chips"])
-    d["memory_s"] = d["bytes_per_device"] / rf.HBM_BW
+    chip = peaks(d["device_kind"])
+    d["memory_s"] = d["bytes_per_device"] / chip.hbm_bytes_per_s
     terms = {"compute": d["compute_s"], "memory": d["memory_s"],
              "collective": d["collective_s"]}
     d["bottleneck"] = max(terms, key=terms.get)
     d["step_s"] = max(terms.values())
-    ideal = mf / (d["n_chips"] * rf.PEAK_FLOPS)
+    ideal = mf / (d["n_chips"] * chip.bf16_flops)
     d["roofline_fraction"] = ideal / d["step_s"] if d["step_s"] else 0.0
     return d
 

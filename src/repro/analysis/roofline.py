@@ -1,8 +1,11 @@
-"""Three-term roofline from compiled dry-run artifacts (TPU v5e target).
+"""Three-term roofline from compiled dry-run artifacts.
 
   compute term    = HLO_FLOPs_per_device / peak_FLOPs
   memory term     = HLO_bytes_per_device / HBM_bw
   collective term = wire_bytes_per_device(ICI)/ICI_bw + (DCN)/DCN_bw
+
+Peaks come from ``analysis/peaks.py`` for the cell's ``device_kind`` (the
+dry-run names its target kind).
 
 ``cost_analysis()`` reports per-device FLOPs/bytes (verified: scan bodies
 are multiplied by trip count); collective bytes come from analysis/hlo.py.
@@ -17,14 +20,9 @@ from typing import Optional
 
 import jax
 
-from repro.analysis import hlo as hlo_mod
+from repro.analysis.peaks import peaks
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.core.headroom import RooflineTerms
-
-PEAK_FLOPS = 197e12        # bf16 / chip
-HBM_BW = 819e9             # bytes/s / chip
-ICI_BW = 50e9              # bytes/s / link (~per-chip effective, one direction)
-DCN_BW = 6.25e9            # bytes/s / chip across pods (50 Gbps)
 
 
 # ---------------------------------------------------------------------------
@@ -154,6 +152,7 @@ class CellRoofline:
     useful_ratio: float
     peak_memory_bytes: float
     argument_bytes: float
+    device_kind: str
     collectives: dict = field(default_factory=dict)
 
     def terms(self) -> RooflineTerms:
@@ -166,7 +165,8 @@ class CellRoofline:
     @property
     def roofline_fraction(self) -> float:
         """Fraction of ideal compute-bound throughput (MFU-like, modeled)."""
-        ideal = self.model_flops / (self.n_chips * PEAK_FLOPS)
+        ideal = self.model_flops / (
+            self.n_chips * peaks(self.device_kind).bf16_flops)
         return ideal / self.step_s if self.step_s else 0.0
 
     def to_dict(self):
@@ -193,7 +193,7 @@ class CellRoofline:
 
 
 def analyze(cfg: ArchConfig, shape: ShapeConfig, mesh_name: str,
-            n_chips: int, compiled, lowered=None,
+            n_chips: int, compiled, *, device_kind: str,
             pod_size: int = 256) -> CellRoofline:
     from repro.analysis import hlocost
     ma = compiled.memory_analysis()
@@ -207,10 +207,11 @@ def analyze(cfg: ArchConfig, shape: ShapeConfig, mesh_name: str,
     bytes_acc = analytic_memory_bytes(cfg, shape, n_chips, n_model)
     summ = costs.summary()
 
-    compute_s = flops / PEAK_FLOPS
-    memory_s = bytes_acc / HBM_BW
-    collective_s = (summ.ici_wire_bytes / ICI_BW
-                    + summ.dcn_wire_bytes / DCN_BW)
+    chip = peaks(device_kind)
+    compute_s = flops / chip.bf16_flops
+    memory_s = bytes_acc / chip.hbm_bytes_per_s
+    collective_s = (summ.ici_wire_bytes / chip.ici_bytes_per_s
+                    + summ.dcn_wire_bytes / chip.dcn_bytes_per_s)
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     mf = model_flops(cfg, shape)
@@ -223,13 +224,9 @@ def analyze(cfg: ArchConfig, shape: ShapeConfig, mesh_name: str,
         bottleneck=max(terms, key=terms.get),
         model_flops=mf, hlo_flops_global=hlo_global,
         useful_ratio=mf / hlo_global if hlo_global else 0.0,
-        # 0.4.x CompiledMemoryStats has no peak rollup; the components
-        # bound it from below (args + outputs + temps live concurrently)
-        peak_memory_bytes=float(getattr(
-            ma, "peak_memory_in_bytes",
-            ma.argument_size_in_bytes + ma.output_size_in_bytes
-            + ma.temp_size_in_bytes)),
+        peak_memory_bytes=float(ma.peak_memory_in_bytes),
         argument_bytes=float(ma.argument_size_in_bytes),
+        device_kind=device_kind,
         collectives=dict(summ.to_dict(),
                          hbm_bytes_upper_bound=costs.hbm_bytes),
     )

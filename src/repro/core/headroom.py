@@ -26,6 +26,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from repro.analysis import peaks
 from repro.experiments.measure import measure
 from repro.experiments.record import Record
 
@@ -130,7 +131,8 @@ class RooflineTerms:
         return max(self.compute_s, self.memory_s, self.collective_s)
 
 
-def derived_headroom(t: RooflineTerms, peak_flops: float = 197e12) -> dict:
+def derived_headroom(t: RooflineTerms,
+                     device_kind: str = peaks.DRYRUN_KIND) -> dict:
     """Headroom while the dominant resource is saturated (the paper's Q1).
 
     When the step is collective-bound, compute sits idle for
@@ -145,7 +147,8 @@ def derived_headroom(t: RooflineTerms, peak_flops: float = 197e12) -> dict:
         "step_s": t.step_s,
         "headroom_s": headroom_s,
         "headroom_fraction": headroom_s / t.step_s if t.step_s else 0.0,
-        "free_offload_gflops": headroom_s * peak_flops / 1e9,
+        "free_offload_gflops": (headroom_s
+                                * peaks.peaks(device_kind).bf16_flops / 1e9),
         "advice": _advice(t),
     }
 

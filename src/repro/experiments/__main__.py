@@ -106,6 +106,8 @@ def main(argv: Optional[list[str]] = None) -> int:
         print(f"no experiments match --only {args.only!r}", file=sys.stderr)
         return 2
 
+    from repro import runtime
+    runtime.enable_compile_cache()
     tracer = None
     if args.trace_out:
         # installed thread-locally: every traced layer (serve engines,

@@ -79,28 +79,22 @@ def _git_commit() -> Optional[str]:
     return sha if p.returncode == 0 and sha else None
 
 
-def _device_count() -> int:
-    try:
-        import jax
-        return len(jax.devices())
-    except Exception:
-        return 0
-
-
-def _environment(ndev: int) -> dict:
+def _environment() -> dict:
     """Uniform environment stamp every emitted Record carries
-    (``params["env"]``): the JAX backend, device count, platform and
-    hostname.  ``diff`` refuses to gate thresholds across rows whose
-    (backend, platform) differ — a CPU-vs-TPU "regression" is a
-    comparison error, not a regression (``--ignore-env`` overrides)."""
+    (``params["env"]``): the JAX backend, device kind and count, host
+    platform and hostname.  ``diff`` refuses to gate thresholds across
+    rows whose (backend, platform) differ — a CPU-vs-TPU "regression" is
+    a comparison error, not a regression (``--ignore-env`` overrides).
+    A backend that fails to initialize raises here: a run never records
+    a device it could not see."""
     import platform
     import sys as _sys
-    try:
-        import jax
-        backend = jax.default_backend()
-    except Exception:
-        backend = "unknown"
-    return {"backend": backend, "device_count": ndev,
+
+    import jax
+    devices = jax.devices()
+    return {"backend": jax.default_backend(),
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
             "platform": _sys.platform, "hostname": platform.node()}
 
 
@@ -134,9 +128,9 @@ class Runner:
     def run(self, emit: Optional[Callable[[Record], None]] = None,
             verbose: bool = False) -> RunReport:
         report = RunReport()
-        ndev = _device_count()
+        env = _environment()
+        ndev = env["device_count"]
         commit = _git_commit()
-        env = _environment(ndev)
         report.records_path, stream = self._open_stream()
 
         def out(r: Record) -> Record:

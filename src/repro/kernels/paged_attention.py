@@ -122,7 +122,7 @@ def paged_attention_fwd(q, pool, tables, lengths, *, buffer_depth=2,
         num_scalar_prefetch=2,
         grid=(S,),
         in_specs=[pl.BlockSpec((None, H, hd), lambda s, *_: (s, 0, 0)),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],   # pool stays HBM
+                  pl.BlockSpec(memory_space=pl.ANY)],   # pool stays HBM
         out_specs=pl.BlockSpec((None, H, hd), lambda s, *_: (s, 0, 0)),
         scratch_shapes=[pltpu.VMEM((depth, page_size, kv2, hd), pool.dtype),
                         pltpu.SemaphoreType.DMA((depth,))],

@@ -1,15 +1,14 @@
 """int8 quantize/dequantize Pallas TPU kernels.
 
 The compute hot-spot of the in-path gradient compression (the paper's
-offloaded transform).  Rowwise symmetric scales; blocks (block_rows, C)
+offloaded transform).  Rowwise symmetric scales; (rows, cols) tiles
 stream through VMEM so the transform runs at HBM bandwidth.
 
 ``interpret=None`` (the default) resolves per backend: compiled Mosaic /
 Triton on TPU and GPU, interpreter on CPU — keyed on
-``jax.default_backend()``, never on the jax version.  Row counts that are
-not a multiple of ``block_rows`` are zero-padded up to the next block and
-the pad rows sliced off the result (the seed asserted instead, which made
-ragged callers fail silently at trace time).
+``jax.default_backend()``, never on the jax version.  Ragged row and
+column counts are zero-padded up to the tile and the padding sliced off
+the result.
 """
 from __future__ import annotations
 
@@ -35,21 +34,48 @@ def resolve_interpret(interpret):
     return interpret
 
 
-def _pad_rows(x, block_rows):
-    """Zero-pad axis 0 up to a multiple of block_rows.  Returns (x, pad)."""
-    pad = (-x.shape[0]) % block_rows
+# Bytes of one f32 input tile.  Mosaic's scoped VMEM is 16 MiB and the
+# pipeline double-buffers every operand, so a (block_rows, C) tile of a
+# long row (a 16 MiB bucket chunked over 4 devices is 4 rows of 1M) does
+# not fit; such rows are split into column tiles of at most this size.
+_TILE_BYTES = 2 << 20
+
+
+def _pad_axis(x, axis, multiple):
+    """Zero-pad ``axis`` up to a multiple of ``multiple``.  Returns
+    (x, pad)."""
+    pad = (-x.shape[axis]) % multiple
     if pad:
-        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        widths = [(0, 0)] * x.ndim
+        widths[axis] = (0, pad)
+        x = jnp.pad(x, widths)
     return x, pad
 
 
-def _quant_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)
-    amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-    scale = jnp.maximum(amax, 1e-12) / 127.0
-    q = jnp.clip(jnp.round(x / scale), -127, 127)
+def _tiles(N, C, block_rows):
+    """(rows, cols) of one tile: whole rows where ``block_rows`` of them
+    fit ``_TILE_BYTES`` as f32, else column tiles of a multiple of 128."""
+    br = min(block_rows, N)
+    if br * C * 4 <= _TILE_BYTES:
+        return br, C
+    return br, max(128, _TILE_BYTES // (4 * br) // 128 * 128)
+
+
+def _amax_kernel(x_ref, a_ref):
+    # the column axis is the inner grid axis: the (br, 1) output block
+    # stays resident across it and accumulates the row maxima
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        a_ref[...] = jnp.zeros_like(a_ref)
+
+    a_ref[...] = jnp.maximum(a_ref[...], jnp.max(
+        jnp.abs(x_ref[...].astype(jnp.float32)), axis=-1, keepdims=True))
+
+
+def _quant_kernel(x_ref, s_ref, q_ref):
+    q = jnp.clip(jnp.round(x_ref[...].astype(jnp.float32) / s_ref[...]),
+                 -127, 127)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale.astype(jnp.float32)
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
@@ -58,23 +84,31 @@ def _dequant_kernel(q_ref, s_ref, x_ref):
 
 
 def quantize_int8(x, *, block_rows=256, interpret=None):
-    """x: (N, C) -> (q int8 (N, C), scale fp32 (N, 1))."""
+    """x: (N, C) -> (q int8 (N, C), scale fp32 (N, 1)).
+
+    Two passes over (rows, cols) tiles: the row maxima, then the scaled
+    rounding.  Zero padding (rows to the tile, columns to the tile) leaves
+    every real row's maximum unchanged and is sliced off."""
     N, C = x.shape
     interpret = resolve_interpret(interpret)
-    block_rows = min(block_rows, N)
-    x, pad = _pad_rows(x, block_rows)
-    grid = ((N + pad) // block_rows,)
-    q, s = pl.pallas_call(
-        _quant_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, C), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((block_rows, C), lambda i: (i, 0)),
-                   pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((N + pad, C), jnp.int8),
-                   jax.ShapeDtypeStruct((N + pad, 1), jnp.float32)],
+    br, bc = _tiles(N, C, block_rows)
+    x, _ = _pad_axis(x, 0, br)
+    x, _ = _pad_axis(x, 1, bc)
+    grid = (x.shape[0] // br, x.shape[1] // bc)
+    tile = pl.BlockSpec((br, bc), lambda i, j: (i, j))
+    row = pl.BlockSpec((br, 1), lambda i, j: (i, 0))
+    amax = pl.pallas_call(
+        _amax_kernel, grid=grid, in_specs=[tile], out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((x.shape[0], 1), jnp.float32),
         interpret=interpret,
     )(x)
-    return (q[:N], s[:N]) if pad else (q, s)
+    s = jnp.maximum(amax, 1e-12) / 127.0
+    q = pl.pallas_call(
+        _quant_kernel, grid=grid, in_specs=[tile, row], out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct(x.shape, jnp.int8),
+        interpret=interpret,
+    )(x, s)
+    return q[:N, :C], s[:N]
 
 
 def dequantize_int8(q, scale, dtype=jnp.float32, *, block_rows=256,
@@ -82,17 +116,17 @@ def dequantize_int8(q, scale, dtype=jnp.float32, *, block_rows=256,
     """q: (N, C) int8, scale: (N, 1) -> (N, C) dtype."""
     N, C = q.shape
     interpret = resolve_interpret(interpret)
-    block_rows = min(block_rows, N)
-    q, pad = _pad_rows(q, block_rows)
-    scale, _ = _pad_rows(scale, block_rows)
-    grid = ((N + pad) // block_rows,)
+    br, bc = _tiles(N, C, block_rows)
+    q, _ = _pad_axis(q, 0, br)
+    q, _ = _pad_axis(q, 1, bc)
+    scale, _ = _pad_axis(scale, 0, br)
     x = pl.pallas_call(
         _dequant_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, C), lambda i: (i, 0)),
-                  pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((block_rows, C), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((N + pad, C), dtype),
+        grid=(q.shape[0] // br, q.shape[1] // bc),
+        in_specs=[pl.BlockSpec((br, bc), lambda i, j: (i, j)),
+                  pl.BlockSpec((br, 1), lambda i, j: (i, 0))],
+        out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct(q.shape, dtype),
         interpret=interpret,
     )(q, scale)
-    return x[:N] if pad else x
+    return x[:N, :C]

@@ -39,14 +39,19 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     S = s_scratch[...]                            # (dh, dh)
 
     lw = jnp.log(jnp.maximum(w, 1e-12))
-    cl = jnp.cumsum(lw, axis=0)                   # inclusive
+    li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    mi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum over the chunk as a lower-triangular ones
+    # matmul: Mosaic has no cumsum lowering, and HIGHEST keeps the f32
+    # log-decays exact on the MXU
+    tril = jnp.where(li >= mi, 1.0, 0.0).astype(jnp.float32)
+    cl = jax.lax.dot(tril, lw, precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
     cl_ex = cl - lw
     r_d = r * jnp.exp(cl_ex)
     k_d = k * jnp.exp(jnp.clip(-cl, max=CLIP))
     scores = jax.lax.dot_general(r_d, k_d, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    mi = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     scores = jnp.where(li > mi, scores, 0.0)      # strictly causal
     y = jax.lax.dot(scores, v, preferred_element_type=jnp.float32)
     y += jax.lax.dot(r_d, S, preferred_element_type=jnp.float32)

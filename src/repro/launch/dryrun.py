@@ -26,6 +26,7 @@ import traceback
 import jax
 
 from repro.analysis import roofline as rf
+from repro.analysis.peaks import DRYRUN_KIND
 from repro.configs import all_archs, live_shapes
 from repro.configs.base import SHAPES
 from repro.launch.mesh import make_production_mesh
@@ -76,21 +77,17 @@ def run_cell(cfg, shape, mesh_name: str, verbose: bool = True,
     t2 = time.time()
     ma = compiled.memory_analysis()
     if verbose:
-        # peak_memory_in_bytes only exists on the new-jax stats object;
-        # 0.4.x reports the components without the rollup
-        peak = getattr(ma, "peak_memory_in_bytes", None)
         print(f"  memory_analysis: args={ma.argument_size_in_bytes/1e9:.3f}GB "
               f"out={ma.output_size_in_bytes/1e9:.3f}GB "
               f"temp={ma.temp_size_in_bytes/1e9:.3f}GB "
-              + (f"peak={peak/1e9:.3f}GB per device" if peak is not None
-                 else "per device"))
+              f"peak={ma.peak_memory_in_bytes/1e9:.3f}GB per device")
         ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):   # 0.4.x wraps the per-device
-            ca = ca[0]                      # dict in a one-element list
-        ca = dict(ca)
         print(f"  cost_analysis: flops={ca.get('flops', 0):.3e} "
               f"bytes={ca.get('bytes accessed', 0):.3e} per device")
-    cell = rf.analyze(cfg, shape, mesh_name, n_chips, compiled)
+    # the placeholder devices stand in for v5e pods: the roofline takes
+    # that chip's peaks (analysis/peaks.py)
+    cell = rf.analyze(cfg, shape, mesh_name, n_chips, compiled,
+                      device_kind=DRYRUN_KIND)
     out = cell.to_dict()
     out["lower_s"] = t1 - t0
     out["compile_s"] = t2 - t1

@@ -18,6 +18,7 @@ import os
 
 import jax
 
+from repro import runtime
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import all_archs, smoke
 from repro.configs.base import ShapeConfig
@@ -70,6 +71,7 @@ def main():
                     help="save a Chrome-trace-event JSON span timeline of "
                          "the run (per-step and checkpoint spans) at PATH")
     args = ap.parse_args()
+    runtime.enable_compile_cache()
 
     base = all_archs()[args.arch]
     cfg = smoke(base) if args.smoke else scaled_config(base, args.scale)

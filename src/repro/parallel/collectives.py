@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from repro.kernels.quant import PALLAS_QUANT_MIN_SIZE  # noqa: F401 — the
 #   auto-dispatch threshold, re-exported for callers/tests of this module
 from repro.parallel import buckets as B
-from repro.parallel import compat
 from repro.parallel import overlap as O
 
 DEFAULT_BUCKET_BYTES = B.DEFAULT_BUCKET_BYTES
@@ -138,7 +137,7 @@ def compressed_psum(x: jax.Array, axis_name: str, mean: bool = True):
     quantization error for error feedback.
     """
     _count_chain()
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     chunks, pad = _to_chunks(x, n)                       # (n, c)
     q, s = quantize_int8(chunks)                         # int8 (n,c), (n,1)
     residual = (chunks - dequantize_int8(q, s)).reshape(-1)
@@ -179,7 +178,7 @@ def pairwise_int8_allreduce(x: jax.Array, axis_name: str, mean: bool = True):
     a 2x DCN saving at n=2 pods (the production mesh); prefer the chunked
     forms only when n is large AND the payload is pod-manual."""
     _count_chain()
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     xf = x.astype(jnp.float32)
     # plain-jnp transform on purpose: the payload may be auto-sharded over
@@ -215,7 +214,7 @@ def ring_allreduce(x: jax.Array, axis_name: str, mean: bool = True,
     (reduced, residual).
     """
     _count_chain()
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     chunks, pad = _to_chunks(x, n)                       # (n, c)

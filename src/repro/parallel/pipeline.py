@@ -17,7 +17,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.parallel import compat
 
 
 def pipeline(stage_fn, n_stages: int, axis_name: str = "stage"):
@@ -49,14 +48,15 @@ def pipeline(stage_fn, n_stages: int, axis_name: str = "stage"):
             sent = jax.lax.ppermute(y, axis_name, fwd_perm)
             return sent, out
 
-        init = compat.pcast_varying(jnp.zeros_like(feed[0]), axis_name)
+        init = jax.lax.pcast(jnp.zeros_like(feed[0]), (axis_name,),
+                             to="varying")
         _, outs = jax.lax.scan(tick, init, feed)
         # stage s emits microbatch m at tick m + s; collect from last stage
         idx = jnp.arange(n_micro) + (n_stages - 1)
         outs = outs[idx]
         # broadcast the last stage's outputs to every stage
         sel = (me == n_stages - 1).astype(outs.dtype)
-        return compat.psum_replicated(outs * sel, axis_name)
+        return jax.lax.psum(outs * sel, axis_name)
 
     return apply
 
@@ -75,7 +75,7 @@ def pipelined_loss(stage_fn, loss_fn, n_stages: int, axis_name: str = "stage"):
         # mask to the last stage before psum: keeps the value exact while
         # leaving a single live backward chain (no n_stages overcount)
         me = jax.lax.axis_index(axis_name)
-        return compat.psum_replicated(
+        return jax.lax.psum(
             jnp.where(me == n_stages - 1, loss, 0.0), axis_name)
 
     return fn

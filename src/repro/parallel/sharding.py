@@ -143,12 +143,9 @@ def best_spec(shape: Sequence[int], candidates: Sequence[Sequence[Optional[str]]
 
 def _current_mesh(ctx: ShardingCtx):
     """Inside shard_map the ambient abstract mesh (with Manual axes) must be
-    used for constraints; otherwise the ctx's concrete mesh.  Old jax has no
-    abstract-mesh accessor (compat returns None) — constraints there always
-    target the concrete mesh."""
-    am = compat.get_abstract_mesh()
-    if am is not None and not am.empty \
-            and set(am.axis_names) == set(ctx.mesh.axis_names):
+    used for constraints; otherwise the ctx's concrete mesh."""
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty and set(am.axis_names) == set(ctx.mesh.axis_names):
         return am
     return ctx.mesh
 

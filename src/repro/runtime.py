@@ -6,8 +6,15 @@ mode.  The offload planner (core/planner.py) can also flip these switches.
 """
 from __future__ import annotations
 
+import os
 import threading
 from contextlib import contextmanager
+
+# fixed, checkout-relative: the cache's entries key on it, so a directory
+# that moved between runs would never hit
+_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
 _DEFAULT = {
     "attention_impl": "xla",    # xla | pallas
@@ -97,3 +104,16 @@ def use_policy(**kwargs):
         yield policy()
     finally:
         _local.policy = prev
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Entry points call this before their first compile (never on import).
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here; otherwise the cache is ``<checkout>/.jax_cache``.
+    """
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
+    return jax.config.jax_compilation_cache_dir

@@ -35,12 +35,15 @@ context and oversubscription; SSM/hybrid/SWA states keep the dense path.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.models import common, transformer
-from repro.parallel import sharding
+from repro.parallel import compat, sharding
 
 
 def paged_supported(cfg: ArchConfig) -> bool:
@@ -125,6 +128,30 @@ def insert_pages(cfg: ArchConfig, pool, base_caches, table_row):
 # paged decode step
 # ---------------------------------------------------------------------------
 
+def _attend(cfg: ArchConfig, q, pool_l, tables, lengths, *, buffer_depth):
+    """``kernels/ops.paged_attention`` over one layer's pool.
+
+    Under a mesh it runs once per 'model' shard inside ``shard_map``:
+    heads are independent, and the compiler cannot partition a Mosaic
+    kernel itself.  A shard holds whole K/V pairs and the query heads
+    that read them when ``num_kv_heads`` divides over 'model'; otherwise
+    every shard attends over all heads.
+    """
+    from repro.kernels import ops as kops
+    attend = functools.partial(kops.paged_attention,
+                               buffer_depth=buffer_depth)
+    ctx = sharding.get_ctx()
+    if ctx is None or not ctx.enabled:
+        return attend(q, pool_l, tables, lengths)
+    heads = ctx.mesh_axes("heads")
+    if not heads or cfg.num_kv_heads % ctx.axis_size("heads"):
+        heads = None
+    q_spec, pool_spec = P(None, heads, None), P(None, None, heads, None)
+    return compat.shard_map(attend, ctx.mesh,
+                            in_specs=(q_spec, pool_spec, P(), P()),
+                            out_specs=q_spec)(q, pool_l, tables, lengths)
+
+
 def _paged_attn_decode(cfg: ArchConfig, p: dict, x, pool_l, idx, tables, *,
                        buffer_depth):
     """Batched one-token paged attention for one layer.
@@ -136,7 +163,6 @@ def _paged_attn_decode(cfg: ArchConfig, p: dict, x, pool_l, idx, tables, *,
     (projection, rope at ``idx``, write-then-attend, output projection)
     with the cache swapped for pool pages.
     """
-    from repro.kernels import ops as kops
     H, Kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     S = x.shape[0]
     bs = pool_l.shape[1]
@@ -158,8 +184,8 @@ def _paged_attn_decode(cfg: ArchConfig, p: dict, x, pool_l, idx, tables, *,
             pool_l, fused[s][None, None], (page, off, 0, 0))
     pool_l = _constrain_pool(pool_l)
 
-    out = kops.paged_attention(q[:, 0], pool_l, tables, idx + 1,
-                               buffer_depth=buffer_depth)    # (S, H, hd)
+    out = _attend(cfg, q[:, 0], pool_l, tables, idx + 1,
+                  buffer_depth=buffer_depth)                 # (S, H, hd)
     out = out.reshape(S, 1, H * hd)
     y = common.dense(p["o"], out)
     return y, pool_l

@@ -540,3 +540,32 @@ def test_planner_serve_offload_slo_arm():
     with runtime.use_policy(serve_slo_attainment_min=0.99):
         strict = planner.make_plan(terms, [], serve_records=good)
     assert strict.serve_offload is False
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_lives_where_the_environment_says(tmp_path, from_env):
+    """``runtime.enable_compile_cache`` (called by every entry point)
+    leaves a set ``JAX_COMPILATION_CACHE_DIR`` to JAX, and otherwise puts
+    the cache at ``<checkout>/.jax_cache``.  Subprocess: it changes the
+    process-wide jax config."""
+    import os
+    import subprocess
+    import sys
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    want = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), ".jax_cache")
+    probe = "print(runtime.enable_compile_cache())"
+    if from_env:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+        # and an entry lands there
+        probe += ("; jax.config.update("
+                  "'jax_persistent_cache_min_compile_time_secs', 0)"
+                  "; jax.jit(lambda x: x * 2)(jax.numpy.ones(3))")
+    out = subprocess.run(
+        [sys.executable, "-c", "import jax; from repro import runtime; "
+         + probe], env=env, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.split()[-1] == want
+    if from_env:
+        assert os.listdir(want)

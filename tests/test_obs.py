@@ -363,8 +363,8 @@ def test_runner_stamps_environment_on_every_record():
     assert report.records
     for r in report.records:
         env = r.params["env"]
-        assert set(env) == {"backend", "device_count", "platform",
-                            "hostname"}
+        assert set(env) == {"backend", "device_kind", "device_count",
+                            "platform", "hostname"}
         assert env["device_count"] >= 1
 
 

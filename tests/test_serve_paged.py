@@ -141,6 +141,13 @@ assert leaf.sharding.shard_shape(leaf.shape)[-2] == leaf.shape[-2] // 2
 _, d4 = run(1, paged=True, depth=4)
 assert d4 == dense
 
+# the Pallas kernel (interpreted here) runs once per 'model' shard inside
+# shard_map, since the compiler cannot partition a Mosaic call: same stream
+from repro import runtime
+with runtime.use_policy(paged_attention_impl="pallas"):
+    _, kern = run(2, paged=True)
+assert kern == dense, (kern, dense)
+
 print("ALL_OK")
 """
 

@@ -1,0 +1,143 @@
+"""Ahead-of-time compiles of the main path's kernels for a TPU v5e.
+
+The TPU compiler ships with jaxlib, so a chip that is described (not
+attached) can compile them here: Mosaic refuses what interpret mode
+accepts (a primitive with no TPU lowering, an unaligned slice, too much
+VMEM), and that refusal costs no chip time.  Each test passes
+``interpret=False`` and asserts the compiled text holds the kernel
+(``tpu_custom_call``).
+
+The topology is described inside a module-scoped fixture, never at import
+time: only one process may load the TPU library, and every pytest-xdist
+worker imports this file.  Where it cannot be described the fixture skips.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import runtime
+from repro.configs import all_archs
+from repro.kernels import flash_attention as fa
+from repro.kernels import paged_attention as pa
+from repro.kernels import quant
+from repro.kernels import rwkv6_scan as rs
+
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one; keep it out while these run
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _sds(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def test_paged_attention_compiles_at_olmo_widths(one_chip):
+    # olmo-1b: 16 MHA heads of 128; 8 slots x 1024 positions in 16-token
+    # pages, plus the trash page
+    q = _sds(one_chip, (8, 16, 128), jnp.bfloat16)
+    pool = _sds(one_chip, (513, 16, 32, 128), jnp.bfloat16)
+    tables = _sds(one_chip, (8, 64), jnp.int32)
+    lengths = _sds(one_chip, (8,), jnp.int32)
+    text = _compiled_text(
+        lambda *a: pa.paged_attention_fwd(*a, interpret=False),
+        q, pool, tables, lengths)
+    assert KERNEL in text
+
+
+def test_flash_attention_compiles_at_olmo_widths(one_chip):
+    x = _sds(one_chip, (1, 512, 16, 128), jnp.bfloat16)
+    text = _compiled_text(
+        lambda q, k, v: fa.flash_attention_fwd(q, k, v, interpret=False),
+        x, x, x)
+    assert KERNEL in text
+
+
+# (4096, 2048): one (2*d_model x d_model) gradient slab of olmo-1b's
+# widths.  (4, 1 << 20): a 16 MiB gradient bucket chunked over 4 devices,
+# whose rows only fit VMEM as column tiles.
+@pytest.mark.parametrize("shape", [(4096, 2048), (4, 1 << 20)])
+@pytest.mark.parametrize("op", ["quantize", "dequantize"])
+def test_int8_quant_compiles_at_olmo_widths(one_chip, op, shape):
+    if op == "quantize":
+        text = _compiled_text(
+            lambda x: quant.quantize_int8(x, interpret=False),
+            _sds(one_chip, shape, jnp.float32))
+    else:
+        text = _compiled_text(
+            lambda q, s: quant.dequantize_int8(q, s, interpret=False),
+            _sds(one_chip, shape, jnp.int8),
+            _sds(one_chip, (shape[0], 1), jnp.float32))
+    assert KERNEL in text
+
+
+def test_rwkv6_scan_compiles_at_rwkv6_7b_widths(one_chip):
+    cfg = all_archs()["rwkv6-7b"]
+    H, dh = cfg.num_heads, cfg.rwkv_head_dim
+    x = _sds(one_chip, (1, 256, H, dh), jnp.float32)
+    u = _sds(one_chip, (H, dh), jnp.float32)
+    text = _compiled_text(
+        lambda r, k, v, w, u: rs.rwkv6_scan_fwd(r, k, v, w, u,
+                                                interpret=False),
+        x, x, x, x, u)
+    assert KERNEL in text
+
+
+def test_olmo_1b_paged_decode_cell_compiles_with_kernel(one_chip):
+    """The engine's decode program at olmo-1b's published widths for the
+    chip_smoke.py engine (8 slots, 1024 positions, 16-token pages).  On
+    this CPU the policy's auto dispatch would pick the XLA twin, so the
+    test steers it to the compiled kernel."""
+    from repro.models import registry
+    from repro.serve.step import make_paged_cells
+
+    cfg = all_archs()["olmo-1b"]
+    n_slots, cache_len, block = 8, 1024, 16
+    n_pages = n_slots * cache_len // block + 1
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    with runtime.use_policy(paged_attention_impl="pallas",
+                            pallas_interpret=False):
+        cells = make_paged_cells(cfg, n_slots, cache_len, block, n_pages)
+        compiled = cells.decode.lower(
+            place(registry.abstract_params(cfg)),
+            _sds(one_chip, (n_slots, 1), jnp.int32),
+            _sds(one_chip, (n_slots,), jnp.int32),
+            place(jax.eval_shape(cells.init_pool)),
+            _sds(one_chip, (n_slots, cells.max_pages), jnp.int32),
+        ).compile()
+    assert KERNEL in compiled.as_text()
+    # params + pool + temps fit the 16 GB chip with room to spare
+    assert compiled.memory_analysis().peak_memory_in_bytes < 8e9
