@@ -1,0 +1,10 @@
+"""idle_share: share of the traced window in which no operation ran on
+the device (1 - the union of device-operation intervals over the window),
+in percent."""
+
+
+def read(run):
+    tr = run.trace
+    if tr is None or tr.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - tr.busy_s() / tr.window_s)
