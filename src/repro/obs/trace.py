@@ -1,4 +1,5 @@
-"""Span tracer with Chrome-trace-event export — virtual-clock aware.
+"""Span tracing with two sinks: Chrome-trace JSON on the engine's clock,
+and spans on the profiler's clock.
 
 A :class:`Tracer` records nested spans (begin/end with name, category,
 args), instant events and counter series on named *tracks* (one per
@@ -31,6 +32,14 @@ converts to the format's microseconds.  Per-track begin/end pairing is
 validated at emission (an unmatched ``end`` is an instrumentation bug
 and raises), so an exported trace is well-formed by construction —
 ``obs.validate`` re-checks it from the outside for CI.
+
+The second sink is :data:`annotate`: a ``jax.profiler.TraceAnnotation``
+(a TraceMe), always emitted.  It lands in a profiler session's trace on
+the same clock as the device's programs, so an idle gap on the device can
+be put down to the host span it fell in; with no session open it costs
+one inactive TraceMe (about a microsecond).  It takes no timestamp and
+calls no engine clock, so the virtual-clock contract above holds for it
+too; its arguments are values the caller already holds.
 """
 from __future__ import annotations
 
@@ -40,7 +49,14 @@ import time
 from contextlib import contextmanager
 from typing import Callable, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs.metrics import MetricsRegistry, _NullMetrics
+
+# ``with annotate("serve.decode", step=n, active=k): ...`` — a span on the
+# profiler's clock named ``name`` whose keyword arguments become the
+# event's stats (module docstring)
+annotate = TraceAnnotation
 
 
 class Tracer:

@@ -25,6 +25,14 @@ Mechanics (DESIGN.md section 11):
   wait / TTFT / per-token decode) are taken on the engine clock; the
   clock is injectable (``clock=...``) so tests drive arrivals on virtual
   time and the ``serve.load_sweep`` experiment uses the wall clock.
+  ``ServeRequest.token_t`` stamps the time each token reached the host.
+* **Profiler spans.**  Each layer boundary of the loop is a ``serve.*``
+  span on the profiler's clock (``obs.trace.annotate``): ``ingest``,
+  ``admit`` (holding ``prefill``, ``first_token`` and ``insert``),
+  ``decode``, ``sample``, ``book`` and ``idle``.  Spans of one request
+  share ``rid``, those of one iteration ``step``; in a profile, each idle
+  gap of the device falls inside the span of what the host was doing
+  (DESIGN.md section 16).
 * **Idle hook.**  When a loop iteration has nothing to decode or admit
   (traffic gap), ``run(..., idle_hook=...)`` invokes the hook — the
   load-sweep experiment mounts a probe kernel there and reports its
@@ -72,6 +80,7 @@ from repro import runtime
 from repro.configs.base import ArchConfig
 from repro.obs import trace as obs_trace
 from repro.obs.logbuf import BoundedLog
+from repro.obs.trace import annotate
 from repro.parallel import compat
 from repro.serve.kv import KVBlockAllocator, blocks_for
 from repro.serve.scheduler import ServeRequest, SlotScheduler
@@ -288,26 +297,33 @@ class ContinuousEngine:
         if tr.enabled:
             tr.begin("engine", "prefill", "engine",
                      t=self._T(now + stall_s), rid=req.rid)
-        logits, slot_caches = self._prefill(
-            self.params, jnp.asarray(req.prompt, jnp.int32)[None])
-        first = int(jnp.argmax(logits[0, -1]))
-        if self.paged:
-            # the request's pages, trash-padded to the fixed table width;
-            # insertion scatters the whole prefill cache into them
-            row = np.asarray(
-                self.kv.padded_table(req.rid, self.cells.max_pages),
-                np.int32)
-            self._pool = self._insert(self._pool, slot_caches,
-                                      jnp.asarray(row))
-            self._tables_np[slot] = row
-            self._tables_dev = jnp.asarray(self._tables_np)
-        else:
-            self._caches = self._insert(self._caches, slot_caches,
-                                        jnp.int32(slot))
+        with annotate("serve.admit", rid=req.rid, slot=slot,
+                      prompt_tokens=len(req.prompt)):
+            with annotate("serve.prefill"):
+                logits, slot_caches = self._prefill(
+                    self.params, jnp.asarray(req.prompt, jnp.int32)[None])
+            with annotate("serve.first_token"):
+                first = int(jnp.argmax(logits[0, -1]))
+            with annotate("serve.insert"):
+                if self.paged:
+                    # the request's pages, trash-padded to the fixed table
+                    # width; insertion scatters the whole prefill cache
+                    # into them
+                    row = np.asarray(
+                        self.kv.padded_table(req.rid, self.cells.max_pages),
+                        np.int32)
+                    self._pool = self._insert(self._pool, slot_caches,
+                                              jnp.asarray(row))
+                    self._tables_np[slot] = row
+                    self._tables_dev = jnp.asarray(self._tables_np)
+                else:
+                    self._caches = self._insert(self._caches, slot_caches,
+                                                jnp.int32(slot))
         self._tok[slot] = first
         self._idx[slot] = len(req.prompt)
         req.generated.append(first)
         req.t_first_token = self.clock() - self._t0
+        req.token_t.append(req.t_first_token)
         if tr.enabled:
             # clamp against the synthetic stall extent so the engine track
             # stays monotone even when a virtual clock's tick is smaller
@@ -317,15 +333,15 @@ class ContinuousEngine:
             tr.instant("engine", "insert", "engine", t=self._T(t_end),
                        rid=req.rid, slot=slot, paged=self.paged)
             tr.end("engine", t=self._T(t_end), rid=req.rid)   # admit
-            tr.metrics.observe("prefill_s", req.t_first_token - now)
         if len(req.generated) >= req.max_new_tokens:
             self.scheduler.complete(slot, req.t_first_token)
             self._reset_slot(slot, t_rel=max(req.t_first_token,
                                              now + stall_s))
         return req.rid
 
-    def _decode_once(self) -> list[int]:
-        """One synchronized decode step for every active slot."""
+    def _decode_once(self, step: int):
+        """Dispatch one decode step for every active slot and wait for its
+        tokens; ``_book`` then hands them to the requests."""
         active = self.scheduler.active()
         t_start = self.clock() - self._t0
         tr = self.tracer
@@ -347,32 +363,41 @@ class ContinuousEngine:
                          condition=self.fabric.condition.name)
                 tr.end("engine", t=self._T(t_start + stall_s),
                        stalled_s=stall_s)
-        if self.paged:
-            logits, self._pool = self._decode(
-                self.params, jnp.asarray(self._tok)[:, None],
-                jnp.asarray(self._idx), self._pool, self._tables_dev)
-            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))  # host sync
-        else:
-            logits, self._caches = self._decode(
-                self.params, jnp.asarray(self._tok)[:, None, None],
-                jnp.asarray(self._idx), self._caches)
-            nxt = np.asarray(jnp.argmax(logits[:, 0, -1], axis=-1))  # host
+        with annotate("serve.decode", step=step, active=len(active)):
+            if self.paged:
+                logits, self._pool = self._decode(
+                    self.params, jnp.asarray(self._tok)[:, None],
+                    jnp.asarray(self._idx), self._pool, self._tables_dev)
+            else:
+                logits, self._caches = self._decode(
+                    self.params, jnp.asarray(self._tok)[:, None, None],
+                    jnp.asarray(self._idx), self._caches)
+        with annotate("serve.sample", step=step):
+            last = logits[:, 0] if self.paged else logits[:, 0, -1]
+            nxt = np.asarray(jnp.argmax(last, axis=-1))     # host sync
         now = self.clock() - self._t0
-        t_end = max(now, t_start + stall_s)
+        return active, nxt, t_start, now, max(now, t_start + stall_s)
+
+    def _book(self, active, nxt, t_start: float, now: float,
+              t_end: float) -> list[int]:
+        """Append a decode step's tokens to their requests; complete and
+        reset the slots that are done; close the step's engine-track span
+        that ``_decode_once`` opened."""
         decoded = []
         for slot, req in active:
             tok = int(nxt[slot])
             req.generated.append(tok)
             req.decode_token_s.append(now - t_start)
+            req.token_t.append(now)
             self._tok[slot] = tok
             self._idx[slot] += 1
             decoded.append(req.rid)
             if len(req.generated) >= req.max_new_tokens:
                 self.scheduler.complete(slot, now)
                 self._reset_slot(slot, t_rel=t_end)
-        if tr.enabled:
-            tr.end("engine", t=self._T(t_end), n_decoded=len(decoded))
-            tr.metrics.observe("decode_tick_s", now - t_start)
+        if self.tracer.enabled:
+            self.tracer.end("engine", t=self._T(t_end),
+                            n_decoded=len(decoded))
         return decoded
 
     def _reset_slot(self, slot: int, t_rel: Optional[float] = None) -> None:
@@ -459,48 +484,53 @@ class ContinuousEngine:
                     r.t_shed, r.shed_reason = now, "deadline"
                 n_seen = len(arrivals)
                 break
-            while n_seen < len(arrivals) \
-                    and arrivals[n_seen].arrival_s <= now:
-                self.scheduler.submit(arrivals[n_seen], now)
-                n_seen += 1
+            due = n_seen
+            while due < len(arrivals) and arrivals[due].arrival_s <= now:
+                due += 1
+            if due > n_seen:
+                with annotate("serve.ingest", n=due - n_seen):
+                    for r in arrivals[n_seen:due]:
+                        self.scheduler.submit(r, now)
+                n_seen = due
             admitted = []
             for _ in range(self.prefill_per_step):
                 rid = self._admit_one(self.clock() - self._t0)
                 if rid is None:
                     break
                 admitted.append(rid)
-            decoded = self._decode_once() if self.scheduler.n_active else []
-            if not admitted and not decoded:
+            step = len(self.step_log) + self.step_log.dropped
+            sampled = self._decode_once(step) if self.scheduler.n_active \
+                else None
+            if not admitted and sampled is None:
                 self.idle_iters += 1
                 if tr.enabled:
                     if not self._idle_open:
                         tr.begin("engine", "idle", "engine", t=self._T(now))
                         self._idle_open = True
                     tr.metrics.count("idle_iters")
-                if idle_hook is not None:
-                    idle_hook()
-                else:
-                    time.sleep(self.IDLE_SLEEP_S)
+                with annotate("serve.idle"):
+                    if idle_hook is not None:
+                        idle_hook()
+                    else:
+                        time.sleep(self.IDLE_SLEEP_S)
                 continue
-            if tr.enabled:
-                # per-iteration pool/queue watermarks, each on its own
-                # counter track (timestamps are this iteration's loop-top
-                # time, monotone per track by construction)
-                tr.counter("queue", "queue_depth", t=self._T(now),
-                           depth=len(self.scheduler.pending))
-                tr.counter("slots", "slot_occupancy", t=self._T(now),
-                           active=self.scheduler.n_active)
-                tr.counter("kv", "kv_pages", t=self._T(now),
-                           free=self.kv.n_free, used=self.kv.n_used)
-                tr.metrics.gauge("queue_depth",
-                                 float(len(self.scheduler.pending)))
-                tr.metrics.gauge("slot_occupancy",
-                                 float(self.scheduler.n_active))
-                tr.metrics.gauge("kv_pages_free", float(self.kv.n_free))
-                tr.metrics.count("work_iters")
-            self.step_log.append(StepEvent(
-                now=now, admitted=tuple(admitted), decoded=tuple(decoded),
-                queued=len(self.scheduler.pending)))
+            with annotate("serve.book", step=step):
+                decoded = self._book(*sampled) if sampled else []
+                if tr.enabled:
+                    # per-iteration pool/queue watermarks, each on its own
+                    # counter track (timestamps are this iteration's
+                    # loop-top time, monotone per track by construction)
+                    tr.counter("queue", "queue_depth", t=self._T(now),
+                               depth=len(self.scheduler.pending))
+                    tr.counter("slots", "slot_occupancy", t=self._T(now),
+                               active=self.scheduler.n_active)
+                    tr.counter("kv", "kv_pages", t=self._T(now),
+                               free=self.kv.n_free, used=self.kv.n_used)
+                    tr.metrics.count("work_iters")
+                self.step_log.append(StepEvent(
+                    now=now, admitted=tuple(admitted),
+                    decoded=tuple(decoded),
+                    queued=len(self.scheduler.pending)))
         if tr.enabled:
             # a still-open merged idle span (the loop drained while idle)
             # closes at the last loop-top time seen

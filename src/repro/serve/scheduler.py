@@ -66,6 +66,9 @@ class ServeRequest:
     shed_reason: str = ""
     n_preempted: int = 0
     decode_token_s: list = field(default_factory=list)  # per token after first
+    # engine-clock time each generated token reached the host: the first
+    # is ``t_first_token``, each later one its decode step's end
+    token_t: list = field(default_factory=list)
 
     @property
     def state(self) -> str:
@@ -248,6 +251,7 @@ class SlotScheduler:
         self.slots[slot] = None
         req.generated.clear()
         req.decode_token_s.clear()
+        req.token_t.clear()
         req.t_admit = None
         req.t_first_token = None
         req.n_preempted += 1

@@ -15,6 +15,7 @@ resharding shows up as a collective-count mismatch (guarded in
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -134,6 +135,18 @@ def slot_cache_shardings(slot_cache_shape, ctx: sharding.ShardingCtx):
     return jax.tree_util.tree_map_with_path(
         lambda path, leaf: compat.named_sharding(ctx.mesh, spec(path, leaf)),
         slot_cache_shape)
+
+
+def _in_ctx(ctx: sharding.ShardingCtx, fn: Callable) -> Callable:
+    """``fn`` traced under the sharding rules ``ctx``, keeping its name:
+    the engine's cells are jitted as ``serve_prefill``, ``serve_decode``
+    and ``serve_insert`` in every build, so their XLA modules are
+    ``jit_serve_*`` whether or not they run over a mesh."""
+    @functools.wraps(fn)
+    def wrapped(*args):
+        with sharding.use_ctx(ctx):
+            return fn(*args)
+    return wrapped
 
 
 @dataclass
@@ -296,15 +309,15 @@ def make_paged_cells(cfg: ArchConfig, n_slots: int, cache_len: int,
 
     paged.check_paged(cfg, cache_len, block_size)
 
-    def _prefill(params, tokens):
+    def serve_prefill(params, tokens):
         return registry.prefill(cfg, params, {"tokens": tokens},
                                 cache_len=cache_len)
 
-    def _decode(params, tokens, index, pool, tables):
+    def serve_decode(params, tokens, index, pool, tables):
         return paged.paged_decode_step(cfg, params, tokens, index, pool,
                                        tables, buffer_depth=buffer_depth)
 
-    def _insert(pool, base_caches, table_row):
+    def serve_insert(pool, base_caches, table_row):
         return paged.insert_pages(cfg, pool, base_caches, table_row)
 
     if mesh is None:
@@ -312,9 +325,9 @@ def make_paged_cells(cfg: ArchConfig, n_slots: int, cache_len: int,
             cfg=cfg, n_slots=n_slots, cache_len=cache_len,
             block_size=block_size, n_pages=n_pages,
             buffer_depth=buffer_depth,
-            prefill=jax.jit(_prefill),
-            decode=jax.jit(_decode, donate_argnums=3),
-            insert=jax.jit(_insert, donate_argnums=0))
+            prefill=jax.jit(serve_prefill),
+            decode=jax.jit(serve_decode, donate_argnums=3),
+            insert=jax.jit(serve_insert, donate_argnums=0))
 
     ctx = sharding.ShardingCtx(
         mesh, sharding.decode_rules("pod" in mesh.axis_names, False))
@@ -329,26 +342,17 @@ def make_paged_cells(cfg: ArchConfig, n_slots: int, cache_len: int,
     bspec = cache_shardings(base_shape, ctx)
     rep = compat.named_sharding(mesh, P())
 
-    def pre(params, tokens):
-        with sharding.use_ctx(ctx):
-            return _prefill(params, tokens)
-
-    def dec(params, tokens, index, pool, tables):
-        with sharding.use_ctx(ctx):
-            return _decode(params, tokens, index, pool, tables)
-
-    def ins(pool, base_caches, table_row):
-        with sharding.use_ctx(ctx):
-            return _insert(pool, base_caches, table_row)
-
     return PagedServeCells(
         cfg=cfg, n_slots=n_slots, cache_len=cache_len,
         block_size=block_size, n_pages=n_pages, buffer_depth=buffer_depth,
-        prefill=jax.jit(pre, in_shardings=(pspec, rep),
+        prefill=jax.jit(_in_ctx(ctx, serve_prefill),
+                        in_shardings=(pspec, rep),
                         out_shardings=(rep, bspec)),
-        decode=jax.jit(dec, in_shardings=(pspec, rep, rep, poolspec, rep),
+        decode=jax.jit(_in_ctx(ctx, serve_decode),
+                       in_shardings=(pspec, rep, rep, poolspec, rep),
                        out_shardings=(rep, poolspec), donate_argnums=3),
-        insert=jax.jit(ins, in_shardings=(poolspec, bspec, rep),
+        insert=jax.jit(_in_ctx(ctx, serve_insert),
+                       in_shardings=(poolspec, bspec, rep),
                        out_shardings=poolspec, donate_argnums=0),
         mesh=mesh, ctx=ctx, param_sharding=pspec, pool_sharding=poolspec)
 
@@ -362,7 +366,7 @@ def make_continuous_cells(cfg: ArchConfig, n_slots: int, cache_len: int,
     sequence over 'model'), never the batch=1 long-context cell that
     ``_ctx_for`` would pick: the engine's slot axis is the batch.
     """
-    def _prefill(params, tokens):
+    def serve_prefill(params, tokens):
         return registry.prefill(cfg, params, {"tokens": tokens},
                                 cache_len=cache_len)
 
@@ -370,7 +374,11 @@ def make_continuous_cells(cfg: ArchConfig, n_slots: int, cache_len: int,
         return registry.decode_step(
             cfg, params, {"tokens": tokens, "index": index}, caches)
 
-    def _insert(caches, slot_caches, slot):
+    def serve_decode(params, tokens, index, caches):
+        return jax.vmap(_slot_decode, in_axes=(None, 0, 0, 0))(
+            params, tokens, index, caches)
+
+    def serve_insert(caches, slot_caches, slot):
         return jax.tree_util.tree_map(
             lambda c, p: jax.lax.dynamic_update_slice_in_dim(
                 c, p[None].astype(c.dtype), slot, axis=0),
@@ -379,10 +387,9 @@ def make_continuous_cells(cfg: ArchConfig, n_slots: int, cache_len: int,
     if mesh is None:
         return ServeCells(
             cfg=cfg, n_slots=n_slots, cache_len=cache_len,
-            prefill=jax.jit(_prefill),
-            decode=jax.jit(jax.vmap(_slot_decode, in_axes=(None, 0, 0, 0)),
-                           donate_argnums=3),
-            insert=jax.jit(_insert, donate_argnums=0))
+            prefill=jax.jit(serve_prefill),
+            decode=jax.jit(serve_decode, donate_argnums=3),
+            insert=jax.jit(serve_insert, donate_argnums=0))
 
     ctx = sharding.ShardingCtx(
         mesh, sharding.decode_rules("pod" in mesh.axis_names, False))
@@ -395,21 +402,15 @@ def make_continuous_cells(cfg: ArchConfig, n_slots: int, cache_len: int,
     sspec = slot_cache_shardings(slot_shape, ctx)
     rep = compat.named_sharding(mesh, P())
 
-    def pre(params, tokens):
-        with sharding.use_ctx(ctx):
-            return _prefill(params, tokens)
-
-    def dec(params, tokens, index, caches):
-        with sharding.use_ctx(ctx):
-            return jax.vmap(_slot_decode, in_axes=(None, 0, 0, 0))(
-                params, tokens, index, caches)
-
     return ServeCells(
         cfg=cfg, n_slots=n_slots, cache_len=cache_len,
-        prefill=jax.jit(pre, in_shardings=(pspec, rep),
+        prefill=jax.jit(_in_ctx(ctx, serve_prefill),
+                        in_shardings=(pspec, rep),
                         out_shardings=(rep, bspec)),
-        decode=jax.jit(dec, in_shardings=(pspec, rep, rep, sspec),
+        decode=jax.jit(_in_ctx(ctx, serve_decode),
+                       in_shardings=(pspec, rep, rep, sspec),
                        out_shardings=(rep, sspec), donate_argnums=3),
-        insert=jax.jit(_insert, in_shardings=(sspec, bspec, rep),
+        insert=jax.jit(_in_ctx(ctx, serve_insert),
+                       in_shardings=(sspec, bspec, rep),
                        out_shardings=sspec, donate_argnums=0),
         mesh=mesh, ctx=ctx, param_sharding=pspec, slot_sharding=sspec)

@@ -78,8 +78,6 @@ def train_loop(step_fn: Callable, state, data_cfg: DataConfig,
                 state, metrics = step_fn(state, batch)
                 jax.block_until_ready(metrics["loss"])
             dt = time.perf_counter() - t0
-            if tr.enabled:
-                tr.metrics.observe("train_step_s", dt)
             warn = stats.observe(dt)
             if warn:
                 log(f"[step {step}] {warn}")

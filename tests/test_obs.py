@@ -105,7 +105,7 @@ def test_null_tracer_is_inert():
     with NULL.span("x", "y"):
         pass
     NULL.metrics.count("n")
-    NULL.metrics.observe("h", 1.0)
+    assert NULL.metrics.snapshot() == {"counters": {}}
     assert NULL.events == ()
 
 
@@ -120,18 +120,18 @@ def test_current_use_restores_previous():
 
 
 def test_metrics_registry_counts_gauges_histograms():
+    # the registry keeps counters only: queue depth, slots and KV pages
+    # are counter tracks of the trace, latencies the request stamps
     m = MetricsRegistry()
     m.count("admits")
     m.count("admits", 2)
-    m.gauge("depth", 7.0)
-    for v in (1.0, 2.0, 3.0, 4.0):
-        m.observe("lat_s", v)
+    m.count("sheds", 0.5)
     snap = m.snapshot()
-    assert snap["counters"]["admits"] == 3
-    assert snap["gauges"]["depth"] == 7.0
-    h = snap["histograms"]["lat_s"]
-    assert h["count"] == 4 and h["p50"] == pytest.approx(3.0)
-    assert h["max"] == pytest.approx(4.0)
+    assert snap == {"counters": {"admits": 3.0, "sheds": 0.5}}
+    # a snapshot is a copy: later counts do not reach it
+    m.count("admits")
+    assert snap["counters"]["admits"] == 3.0
+    assert m.snapshot()["counters"]["admits"] == 4.0
 
 
 # ---------------------------------------------------------------------------
@@ -465,3 +465,169 @@ def test_runtime_knob_resolves_fresh_tracer():
     tr2 = Tracer()
     with use(tr2):
         assert resolve() is tr2
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink: serve.* spans, token stamps, program names
+# ---------------------------------------------------------------------------
+
+def _profiled(fn):
+    """Run ``fn()`` under an in-memory profiler session; the ``serve.*``
+    host events it recorded as (name, start_ns, end_ns, stats), by
+    start."""
+    from jax._src.lib import _profiler
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    session = _profiler.ProfilerSession(opts)
+    try:
+        fn()
+    finally:
+        xspace = session.stop()
+    pd = jax.profiler.ProfileData.from_serialized_xspace(xspace)
+    evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+           for plane in pd.planes for line in plane.lines
+           for e in line.events if e.name.startswith("serve.")]
+    return sorted(evs, key=lambda e: (e[1], -e[2]))
+
+
+def test_engine_spans_reach_the_profiler(cfg_params):
+    from repro.serve.continuous import ContinuousEngine
+    from repro.serve.scheduler import ServeRequest
+    c, params = cfg_params
+    base = np.arange(8, dtype=np.int32) % c.vocab_size
+    # the second request arrives long after the first is done, so the
+    # loop idles in between (virtual clock: one second per call)
+    reqs = [ServeRequest(prompt=base, max_new_tokens=3, arrival_s=0.0),
+            ServeRequest(prompt=base[:5], max_new_tokens=3,
+                         arrival_s=40.0)]
+    eng = ContinuousEngine(c, params, n_slots=2, cache_len=32,
+                           block_size=4, clock=_vclock(), paged=True)
+    eng.run([ServeRequest(prompt=base, max_new_tokens=2)])    # compiles
+    evs = _profiled(lambda: eng.run(reqs))
+    names = {e[0] for e in evs}
+    assert names == {"serve.ingest", "serve.admit", "serve.prefill",
+                     "serve.first_token", "serve.insert", "serve.decode",
+                     "serve.sample", "serve.book", "serve.idle"}
+    ingest = [e[3] for e in evs if e[0] == "serve.ingest"]
+    assert ingest == [{"n": 1}, {"n": 1}]
+    admits = [e for e in evs if e[0] == "serve.admit"]
+    assert [a[3] for a in admits] == [
+        {"rid": r.rid, "slot": eng.scheduler.admit_log[i][1],
+         "prompt_tokens": len(r.prompt)} for i, r in enumerate(reqs)]
+    # prefill, first_token and insert lie inside their admission, in order
+    for a in admits:
+        inner = [e for e in evs if a[1] <= e[1] and e[2] <= a[2]
+                 and e is not a]
+        assert [e[0] for e in inner] == ["serve.prefill",
+                                         "serve.first_token",
+                                         "serve.insert"]
+        assert all(e[3] == {} for e in inner)
+    # each decoding iteration: decode, sample, book, sharing its step,
+    # one after another; decode carries the active slots
+    steps = {}
+    for e in evs:
+        if e[0] in ("serve.decode", "serve.sample", "serve.book"):
+            steps.setdefault(e[3]["step"], []).append(e)
+    decoding = [i for i, ev in enumerate(eng.step_log) if ev.decoded]
+    assert sorted(s for s, es in steps.items()
+                  if es[0][0] == "serve.decode") == decoding
+    for s in decoding:
+        es = steps[s]
+        assert [e[0] for e in es] == ["serve.decode", "serve.sample",
+                                      "serve.book"]
+        assert es[0][3] == {"step": s,
+                            "active": len(eng.step_log[s].decoded)}
+        assert es[0][2] <= es[1][1] and es[1][2] <= es[2][1]
+    # every working iteration books once, whether it decoded or not
+    assert sorted(steps) == list(range(len(eng.step_log)))
+    assert all(r.done and len(r.generated) == 3 for r in reqs)
+
+
+def test_token_stamps_follow_generated_tokens(cfg_params):
+    from repro.serve.continuous import ContinuousEngine
+    from repro.serve.scheduler import ClassSLO, ServeRequest, SLOPolicy
+    c, params = cfg_params
+    base = np.arange(8, dtype=np.int32) % c.vocab_size
+    # the preemption scenario: batch requests evicted mid-stream restart
+    # with their stamps cleared
+    reqs = [ServeRequest(prompt=(base + i) % c.vocab_size,
+                         max_new_tokens=12, arrival_s=0.0,
+                         priority="batch") for i in range(4)]
+    reqs += [ServeRequest(prompt=(base + 10 + i) % c.vocab_size,
+                          max_new_tokens=4, arrival_s=3.0 + i,
+                          priority="interactive") for i in range(2)]
+    policy = SLOPolicy(classes={
+        "interactive": ClassSLO(rank=0, ttft_s=6.0, tpot_s=50.0),
+        "batch": ClassSLO(rank=1, ttft_s=500.0, tpot_s=500.0,
+                          shed_after_s=200.0),
+    }, default_class="batch")
+    eng = ContinuousEngine(c, params, n_slots=2, cache_len=32,
+                           block_size=4, clock=_vclock(), slo=policy)
+    eng.run(reqs)
+    assert eng.scheduler.preempt_log
+    for r in reqs:
+        assert r.done and len(r.token_t) == len(r.generated)
+        assert r.token_t[0] == r.t_first_token
+        assert all(a <= b for a, b in zip(r.token_t, r.token_t[1:]))
+        # each later stamp ends the decode tick that decode_token_s times
+        assert len(r.decode_token_s) == len(r.token_t) - 1
+        assert r.token_t[-1] == r.t_done
+
+
+SCRIPT_NAMES = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import re
+import jax
+import jax.numpy as jnp
+from repro.configs import all_archs, smoke
+from repro.models import registry
+from repro.parallel import compat
+from repro.serve.step import make_continuous_cells, make_paged_cells
+
+cfg = smoke(all_archs()["olmo-1b"])
+params = jax.eval_shape(lambda: registry.init_params(cfg, jax.random.key(0)))
+S, L, BS = 2, 32, 4
+i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+mesh = compat.make_mesh((1, 2), ("data", "model"))
+
+def module(fn, *args):
+    return re.search(r"module @(\w+)", fn.lower(*args).as_text()).group(1)
+
+for m in (None, mesh):
+    for paged in (False, True):
+        if paged:
+            cells = make_paged_cells(cfg, S, L, BS, S * L // BS + 1, mesh=m)
+            state = jax.eval_shape(cells.init_pool)
+            dec = (params, i32(S, 1), i32(S), state, i32(S, L // BS))
+            slot = i32(L // BS)
+        else:
+            cells = make_continuous_cells(cfg, S, L, mesh=m)
+            state = jax.eval_shape(cells.init_slot_caches)
+            dec = (params, i32(S, 1, 1), i32(S), state)
+            slot = i32()
+        tokens = i32(1, 8)
+        _, base = jax.eval_shape(cells.prefill, params, tokens)
+        got = (module(cells.prefill, params, tokens),
+               module(cells.decode, *dec),
+               module(cells.insert, state, base, slot))
+        assert got == ("jit_serve_prefill", "jit_serve_decode",
+                       "jit_serve_insert"), (m is not None, paged, got)
+print("ok")
+"""
+
+
+def test_step_programs_have_stable_names():
+    """Every build of the engine's cells (dense and paged, one device and
+    a 2-device mesh) lowers to modules ``jit_serve_prefill``,
+    ``jit_serve_decode`` and ``jit_serve_insert``: the names a profile's
+    readers key on."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", SCRIPT_NAMES], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.strip().endswith("ok")
