@@ -74,13 +74,13 @@ def rwkv6_scan(r, k, v, w, u, s0=None, *, chunk=64):
 
 @partial(jax.jit, static_argnames=("buffer_depth", "use_kernel",
                                    "interpret"))
-def _paged_attention(q, pool, tables, lengths, *, buffer_depth, use_kernel,
-                     interpret):
+def _paged_attention(q, pool, tables, lengths, layer, *, buffer_depth,
+                     use_kernel, interpret):
     if use_kernel:
-        return _pa.paged_attention_fwd(q, pool, tables, lengths,
+        return _pa.paged_attention_fwd(q, pool, tables, lengths, layer,
                                        buffer_depth=buffer_depth,
                                        interpret=interpret)
-    return _pa.paged_attention_xla(q, pool, tables, lengths,
+    return _pa.paged_attention_xla(q, pool, tables, lengths, layer,
                                    buffer_depth=buffer_depth)
 
 
@@ -97,13 +97,15 @@ def use_paged_kernel() -> bool:
     return impl == "pallas"
 
 
-def paged_attention(q, pool, tables, lengths, *, buffer_depth=None):
-    """Policy-dispatched ragged paged-attention decode (see
-    ``kernels/paged_attention.py`` for shapes).  ``buffer_depth=None``
-    reads the ``paged_buffer_depth`` policy knob."""
+def paged_attention(q, pool, tables, lengths, layer=0, *,
+                    buffer_depth=None):
+    """Policy-dispatched ragged paged-attention decode over ``pool``'s
+    layer ``layer`` (see ``kernels/paged_attention.py`` for shapes: a
+    stacked 5-D pool or one 4-D layer).  ``buffer_depth=None`` reads the
+    ``paged_buffer_depth`` policy knob."""
     if buffer_depth is None:
         buffer_depth = int(runtime.policy()["paged_buffer_depth"])
-    return _paged_attention(q, pool, tables, lengths,
+    return _paged_attention(q, pool, tables, lengths, layer,
                             buffer_depth=buffer_depth,
                             use_kernel=use_paged_kernel(),
                             interpret=_interp())

@@ -2,9 +2,14 @@
 
 One query token per sequence attends over that sequence's KV pages in a
 physical block-paged pool (``serve/kv.py`` + ``serve/paged.py``): pool
-layout ``(n_pages, page_size, 2*Kv, hd)`` with K/V *head-interleaved*
+layout ``(L, n_pages, page_size, 2*Kv, hd)`` — every layer's pages stacked,
+as the decode step's layer scan carries them — with K/V *head-interleaved*
 along the fused head axis (``[k0, v0, k1, v1, ...]``, the tpu_commons
-fused-KV layout — one DMA per page moves both halves).  The kernel grid
+fused-KV layout — one DMA per page moves both halves).  A ``layer`` index
+picks the layer in place: the kernel DMAs ``pool[layer, page]`` straight
+out of the stacked pool, so no layer is ever sliced out (a copy of its
+whole pool) before the call.  A 4-D pool ``(n_pages, page_size, 2*Kv,
+hd)`` is one layer, at index 0.  The kernel grid
 is one program per sequence; each program walks its block table (a
 scalar-prefetch array, so page ids are known before the DMAs they index)
 and keeps ``buffer_depth`` page copies in flight: pages ``j+1 ..
@@ -41,15 +46,15 @@ from repro.kernels.quant import resolve_interpret
 NEG_INF = -1e30
 
 
-def _decode_kernel(tables, lengths, q_ref, pool, o_ref, buf, sem, *,
+def _decode_kernel(tables, lengths, layer, q_ref, pool, o_ref, buf, sem, *,
                    page_size, depth, max_pages, n_kv, rep, sm_scale):
     s = pl.program_id(0)
     length = lengths[s]
     n_pages = jax.lax.div(length + page_size - 1, page_size)
 
     def dma(j, slot):
-        return pltpu.make_async_copy(pool.at[tables[s, j]], buf.at[slot],
-                                     sem.at[slot])
+        return pltpu.make_async_copy(pool.at[layer[0], tables[s, j]],
+                                     buf.at[slot], sem.at[slot])
 
     # warm-up: fill the buffer ring before the first wait
     for d in range(min(depth, max_pages)):
@@ -98,17 +103,30 @@ def _decode_kernel(tables, lengths, q_ref, pool, o_ref, buf, sem, *,
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def paged_attention_fwd(q, pool, tables, lengths, *, buffer_depth=2,
+def _stacked(pool, layer):
+    """A 4-D pool as the one layer of a stacked pool (a free reshape),
+    and ``layer`` as an int32 scalar: traced, so one program serves
+    every layer."""
+    if pool.ndim == 4:
+        pool = pool[None]
+    return pool, jnp.asarray(layer, jnp.int32)
+
+
+def paged_attention_fwd(q, pool, tables, lengths, layer=0, *, buffer_depth=2,
                         sm_scale=None, interpret=None):
     """q: (S, H, hd) one decode token per sequence;
-    pool: (n_pages, page_size, 2*Kv, hd) head-interleaved K/V pages;
+    pool: (L, n_pages, page_size, 2*Kv, hd) head-interleaved K/V pages of
+    L layers, or (n_pages, page_size, 2*Kv, hd) for one;
     tables: (S, max_pages) int32 page ids (trash-padded past each
-    sequence's reserved pages); lengths: (S,) valid tokens per sequence.
-    Returns (S, H, hd).  ``buffer_depth`` is the number of page buffers
-    kept in flight (static; clamped to [1, max_pages])."""
+    sequence's reserved pages); lengths: (S,) valid tokens per sequence;
+    layer: the layer of ``pool`` to attend (int or traced int32 scalar;
+    0 for a 4-D pool).  The kernel reads the pages of that layer where
+    they lie.  Returns (S, H, hd).  ``buffer_depth`` is the number of
+    page buffers kept in flight (static; clamped to [1, max_pages])."""
     interpret = resolve_interpret(interpret)
+    pool, layer = _stacked(pool, layer)
     S, H, hd = q.shape
-    _, page_size, kv2, _ = pool.shape
+    _, _, page_size, kv2, _ = pool.shape
     n_kv = kv2 // 2
     rep = H // n_kv
     assert n_kv * rep == H, (H, n_kv)
@@ -119,7 +137,7 @@ def paged_attention_fwd(q, pool, tables, lengths, *, buffer_depth=2,
         _decode_kernel, page_size=page_size, depth=depth,
         max_pages=max_pages, n_kv=n_kv, rep=rep, sm_scale=sm_scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(S,),
         in_specs=[pl.BlockSpec((None, H, hd), lambda s, *_: (s, 0, 0)),
                   pl.BlockSpec(memory_space=pl.ANY)],   # pool stays HBM
@@ -133,18 +151,21 @@ def paged_attention_fwd(q, pool, tables, lengths, *, buffer_depth=2,
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, hd), q.dtype),
         interpret=interpret, name="paged_attention",
-    )(tables, lengths, q, pool)
+    )(tables, lengths, layer.reshape(1), q, pool)
 
 
-def paged_attention_xla(q, pool, tables, lengths, *, buffer_depth=2,
+def paged_attention_xla(q, pool, tables, lengths, layer=0, *, buffer_depth=2,
                         sm_scale=None):
     """Pure-XLA twin of the kernel: scan over the block table in chunks
     of ``buffer_depth`` pages (gathered together, folded into the same
     online softmax).  Identical math and walk order; the depth knob here
     amortizes per-page gather/dispatch overhead rather than overlapping
-    DMA, so the page-size x depth sweep stays observable on CPU."""
+    DMA, so the page-size x depth sweep stays observable on CPU.  The
+    pool and ``layer`` as for the kernel: each chunk gathers
+    ``pool[layer, pages]``."""
+    pool, layer = _stacked(pool, layer)
     S, H, hd = q.shape
-    n_pages_tot, page_size, kv2, _ = pool.shape
+    _, n_pages_tot, page_size, kv2, _ = pool.shape
     n_kv = kv2 // 2
     rep = H // n_kv
     max_pages = tables.shape[1]
@@ -163,7 +184,7 @@ def paged_attention_xla(q, pool, tables, lengths, *, buffer_depth=2,
     def body(carry, inp):
         acc, m, l = carry
         tbl_c, pos_c = inp
-        kv = pool[tbl_c].astype(jnp.float32).reshape(
+        kv = pool[layer, tbl_c].astype(jnp.float32).reshape(
             S, depth * page_size, n_kv, 2, hd)
         k, v = kv[..., 0, :], kv[..., 1, :]
         sc = jnp.einsum("sgrh,stgh->sgrt", qh, k)           # (S,Kv,rep,T)

@@ -2,9 +2,10 @@
 
 The dense continuous engine stacks a full ``cache_len`` KV cache per
 slot; the paged engine replaces that with ONE preallocated pool tensor
-per attention layer — shape ``(G, n_pages, block_size, 2*Kv, hd)`` (group
-scan dim, then pages) with K/V *head-interleaved* on the fused head axis
-(``[k0, v0, k1, v1, ...]``): a page is the unit of both allocation
+per layer of the scanned group, stacked over the group's layers — shape
+``(G, n_pages, block_size, 2*Kv, hd)`` (layer, then pages; the kernel
+reads it at a layer index) — with K/V *head-interleaved* on the fused
+head axis (``[k0, v0, k1, v1, ...]``): a page is the unit of both allocation
 (``serve/kv.py`` block ids ARE page ids) and data movement (one DMA per
 page moves keys and values together).  Requests own pages through the
 allocator's block tables; the device sees fixed-width table rows padded
@@ -21,10 +22,15 @@ Three jit-able pieces (wired into cells by ``serve/step.py``):
   reservation land on the trash page, harmlessly).
 * ``paged_decode_step`` — the batched decode step over all slots: project
   q/k/v per slot, write each slot's new token into its current page
-  (``dynamic_update_slice`` at ``(table[idx // bs], idx % bs)``), then
-  attend over the block table via ``kernels/ops.paged_attention`` — the
-  ragged paged-attention kernel (or its XLA twin) walking pages with
-  ``buffer_depth`` loads in flight.  Non-attention sublayers (norms,
+  (``dynamic_update_slice`` at ``(g, table[idx // bs], idx % bs)`` of the
+  stacked leaf), then attend over the block table via
+  ``kernels/ops.paged_attention`` — the ragged paged-attention kernel (or
+  its XLA twin) walking layer ``g``'s pages with ``buffer_depth`` loads in
+  flight.  The pool rides the layer scan's *carry* (not its scanned
+  inputs and outputs), and the kernel is handed the whole stacked leaf
+  with the layer index: the writes alias the donated pool and the reads
+  address it in place, so a step moves the bytes it attends and its 16
+  token rows, never a copy of the pool.  Non-attention sublayers (norms,
   MLP/MoE, residuals, logits) reuse the exact ``models/transformer`` code,
   which is what keeps paged token streams bit-identical to the dense
   engine at f32 (differential-tested at tp=1/2/4).
@@ -128,44 +134,48 @@ def insert_pages(cfg: ArchConfig, pool, base_caches, table_row):
 # paged decode step
 # ---------------------------------------------------------------------------
 
-def _attend(cfg: ArchConfig, q, pool_l, tables, lengths, *, buffer_depth):
-    """``kernels/ops.paged_attention`` over one layer's pool.
+def _attend(cfg: ArchConfig, q, pool_l, g, tables, lengths, *,
+            buffer_depth):
+    """``kernels/ops.paged_attention`` over layer ``g`` of the stacked
+    leaf ``pool_l``.
 
     Under a mesh it runs once per 'model' shard inside ``shard_map``:
     heads are independent, and the compiler cannot partition a Mosaic
     kernel itself.  A shard holds whole K/V pairs and the query heads
     that read them when ``num_kv_heads`` divides over 'model'; otherwise
-    every shard attends over all heads.
+    every shard attends over all heads.  The layer index is replicated.
     """
     from repro.kernels import ops as kops
     attend = functools.partial(kops.paged_attention,
                                buffer_depth=buffer_depth)
     ctx = sharding.get_ctx()
     if ctx is None or not ctx.enabled:
-        return attend(q, pool_l, tables, lengths)
+        return attend(q, pool_l, tables, lengths, g)
     heads = ctx.mesh_axes("heads")
     if not heads or cfg.num_kv_heads % ctx.axis_size("heads"):
         heads = None
-    q_spec, pool_spec = P(None, heads, None), P(None, None, heads, None)
+    q_spec = P(None, heads, None)
+    pool_spec = P(None, None, None, heads, None)
     return compat.shard_map(attend, ctx.mesh,
-                            in_specs=(q_spec, pool_spec, P(), P()),
-                            out_specs=q_spec)(q, pool_l, tables, lengths)
+                            in_specs=(q_spec, pool_spec, P(), P(), P()),
+                            out_specs=q_spec)(q, pool_l, tables, lengths, g)
 
 
-def _paged_attn_decode(cfg: ArchConfig, p: dict, x, pool_l, idx, tables, *,
-                       buffer_depth):
-    """Batched one-token paged attention for one layer.
+def _paged_attn_decode(cfg: ArchConfig, p: dict, x, pool_l, g, idx, tables,
+                       *, buffer_depth):
+    """Batched one-token paged attention for layer ``g`` of the group.
 
-    x: (S, 1, D) normed activations for every slot; pool_l: (n_pages, bs,
-    2*Kv, hd) — the group dim was consumed by the caller's scan; idx:
-    (S,) per-slot positions; tables: (S, max_pages).  Returns (y (S,1,D),
-    updated pool_l).  Mirrors ``models/attention.attn_decode`` exactly
-    (projection, rope at ``idx``, write-then-attend, output projection)
-    with the cache swapped for pool pages.
+    x: (S, 1, D) normed activations for every slot; pool_l: (G, n_pages,
+    bs, 2*Kv, hd) — the whole stacked leaf, carried by the caller's scan;
+    g: the traced layer index into it; idx: (S,) per-slot positions;
+    tables: (S, max_pages).  Returns (y (S,1,D), updated pool_l).
+    Mirrors ``models/attention.attn_decode`` exactly (projection, rope at
+    ``idx``, write-then-attend, output projection) with the cache swapped
+    for pool pages.
     """
     H, Kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     S = x.shape[0]
-    bs = pool_l.shape[1]
+    bs = pool_l.shape[2]
 
     q = common.dense(p["q"], x).reshape(S, 1, H, hd)
     k = common.dense(p["k"], x).reshape(S, 1, Kv, hd)
@@ -174,28 +184,29 @@ def _paged_attn_decode(cfg: ArchConfig, p: dict, x, pool_l, idx, tables, *,
     q = common.apply_rope(q, pos, cfg.rope_theta)
     k = common.apply_rope(k, pos, cfg.rope_theta)
 
-    # write each slot's new token into its current page — the paged form
-    # of the dense path's cache dynamic_update_slice (free slots write the
-    # trash page: their tables are all-trash, reads stay length-masked)
+    # write each slot's new token into its current page of layer g — the
+    # paged form of the dense path's cache dynamic_update_slice (free
+    # slots write the trash page: their tables are all-trash, reads stay
+    # length-masked)
     fused = fuse_kv(k[:, 0], v[:, 0]).astype(pool_l.dtype)   # (S, 2Kv, hd)
     for s in range(S):
         page, off = tables[s, idx[s] // bs], idx[s] % bs
         pool_l = jax.lax.dynamic_update_slice(
-            pool_l, fused[s][None, None], (page, off, 0, 0))
+            pool_l, fused[s][None, None, None], (g, page, off, 0, 0))
     pool_l = _constrain_pool(pool_l)
 
-    out = _attend(cfg, q[:, 0], pool_l, tables, idx + 1,
+    out = _attend(cfg, q[:, 0], pool_l, g, tables, idx + 1,
                   buffer_depth=buffer_depth)                 # (S, H, hd)
     out = out.reshape(S, 1, H * hd)
     y = common.dense(p["o"], out)
     return y, pool_l
 
 
-def _paged_layer_decode(cfg: ArchConfig, p: dict, x, pool_l, idx, tables, *,
-                        buffer_depth):
+def _paged_layer_decode(cfg: ArchConfig, p: dict, x, pool_l, g, idx, tables,
+                        *, buffer_depth):
     """``transformer._layer_decode`` with paged attention."""
     h = common.norm_apply(cfg, p["norm1"], x)
-    y, pool_l = _paged_attn_decode(cfg, p["attn"], h, pool_l, idx, tables,
+    y, pool_l = _paged_attn_decode(cfg, p["attn"], h, pool_l, g, idx, tables,
                                    buffer_depth=buffer_depth)
     if cfg.parallel_block:
         f, _ = transformer._ffn(cfg, p, h)
@@ -213,18 +224,25 @@ def paged_decode_step(cfg: ArchConfig, params: dict, tokens, idx, pool,
     tokens: (S, 1) int32; idx: (S,) per-slot positions; pool: the
     ``init_kv_pool`` pytree; tables: (S, max_pages) int32.  Returns
     (logits (S, 1, V) fp32, updated pool).
+
+    The scan runs over ``(params["layers"], arange(G))`` and carries the
+    pool: were the pool a scanned input and output, XLA would slice each
+    layer out of it, copy the donated input into a fresh stacked output
+    and write each layer back — three passes over the whole pool a step.
     """
     x = params["embed"]["embedding"][tokens]             # (S, 1, D)
 
-    def body(x, inp):
-        gp, pool_g = inp
-        new = {}
+    def body(carry, inp):
+        x, pool = carry
+        gp, g = inp
+        pool = dict(pool)
         for i in range(cfg.layer_group):
-            x, new[f"l{i}"] = _paged_layer_decode(
-                cfg, gp[f"l{i}"], x, pool_g[f"l{i}"], idx, tables,
+            x, pool[f"l{i}"] = _paged_layer_decode(
+                cfg, gp[f"l{i}"], x, pool[f"l{i}"], g, idx, tables,
                 buffer_depth=buffer_depth)
-        return x, new
+        return (x, pool), None
 
-    x, new_pool = jax.lax.scan(body, x, (params["layers"], pool))
+    (x, new_pool), _ = jax.lax.scan(
+        body, (x, pool), (params["layers"], jnp.arange(cfg.num_groups())))
     x = common.norm_apply(cfg, params["final_norm"], x)
     return transformer._logits(cfg, params, x), new_pool

@@ -221,6 +221,59 @@ def test_paged_attention_ignores_trash_and_pad_positions():
     assert jnp.max(jnp.abs(got - base)) == 0.0
 
 
+def _stacked_case(seed, L, *args):
+    """``_paged_case`` with an (L, n_pages, ...) pool: L layers of pages
+    drawn apart, one block table shared by every layer."""
+    q, pool, tables, lens = _paged_case(seed, *args)
+    layers = [pool] + [jax.random.normal(jax.random.key(seed + i), pool.shape)
+                       for i in range(1, L)]
+    return q, jnp.stack(layers), tables, lens
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_attention_stacked_pool_matches_ref(layer):
+    """Handed a stacked 3-layer pool and a layer index (traced, as the
+    decode step's scan passes it), the kernel (interpret) and the XLA
+    twin attend that layer's pages where they lie: both match the oracle
+    over ``pool[layer]``."""
+    from repro.kernels import paged_attention as pa
+    q, pool, tables, lens = _stacked_case(31, 3, 4, 4, 2, 16, 8, 6,
+                                          (1, 13, 40, 48))
+    want = ref.paged_attention_ref(q, pool[layer], tables, lens)
+    got_k = jax.jit(lambda l: pa.paged_attention_fwd(
+        q, pool, tables, lens, l, buffer_depth=2, interpret=True))(layer)
+    got_x = jax.jit(lambda l: pa.paged_attention_xla(
+        q, pool, tables, lens, l, buffer_depth=2))(layer)
+    assert jnp.max(jnp.abs(got_k - want)) < 2e-5
+    assert jnp.max(jnp.abs(got_x - want)) < 2e-5
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_attention_stacked_ignores_other_layers(layer):
+    """The trash page, the unowned pages and the past-length tails of the
+    attended layer, and every page of the other layers, poisoned: the
+    kernel's output over the stacked pool at ``layer`` equals its output
+    over that layer alone, clean."""
+    import numpy as np
+    from repro.kernels import paged_attention as pa
+    lengths = (5, 17, 26)
+    q, pool, tables, lens = _stacked_case(37, 3, 3, 4, 2, 16, 8, 4, lengths)
+    base = pa.paged_attention_fwd(q, pool[layer], tables, lens,
+                                  buffer_depth=2, interpret=True)
+    tbl = np.asarray(tables)
+    owned = set()
+    for s, n in enumerate(lengths):
+        owned.update(tbl[s, :-(-n // 8)].tolist())
+    poisoned = np.full(pool.shape, 1e6, np.float32)
+    poisoned[layer, sorted(owned)] = np.asarray(pool[layer])[sorted(owned)]
+    for s, n in enumerate(lengths):
+        last = tbl[s, (n - 1) // 8]
+        poisoned[layer, last, n % 8 or 8:] = 1e6
+    got = pa.paged_attention_fwd(q, jnp.asarray(poisoned), tables, lens,
+                                 layer, buffer_depth=2, interpret=True)
+    assert jnp.max(jnp.abs(got - base)) == 0.0
+
+
 def test_paged_attention_policy_dispatch(monkeypatch):
     """``ops.paged_attention`` routes per policy without a stale jit
     cache: ``pallas`` forces the kernel, ``xla`` the twin, ``auto`` keys

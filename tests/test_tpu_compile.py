@@ -13,7 +13,9 @@ worker imports this file.  Where it cannot be described the fixture skips.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,16 +113,50 @@ def test_rwkv6_scan_compiles_at_rwkv6_7b_widths(one_chip):
     assert KERNEL in text
 
 
-def test_olmo_1b_paged_decode_cell_compiles_with_kernel(one_chip):
-    """The engine's decode program at olmo-1b's published widths for the
-    chip_smoke.py engine (8 slots, 1024 positions, 16-token pages).  On
-    this CPU the policy's auto dispatch would pick the XLA twin, so the
-    test steers it to the compiled kernel."""
+def _pool_passes(text: str, page_dims: tuple) -> list[str]:
+    """Copies and dynamic slices in compiled HLO ``text`` whose result
+    ends in the pool's page geometry ``(n_pages, bs, 2*Kv, hd)``: the
+    whole stacked pool, one layer of it, or one layer with a unit axis."""
+    op = re.compile(r"^\s*(?:ROOT )?%([\w.-]+) = \w+\[([\d,]*)\]\S* "
+                    r"([\w-]+)\(")
+    hits = []
+    for line in text.splitlines():
+        m = op.match(line)
+        if not m or not re.search(r"copy|dynamic[-_]slice",
+                                  m.group(1) + " " + m.group(3)):
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        if dims[-4:] == page_dims:
+            hits.append(line.strip()[:160])
+    return hits
+
+
+# (arch, layers kept, slots, positions, peak bound): chip_smoke.py's
+# engine, and the benchmark's two cells (perfbench/configs): olmo-1b at
+# the published context and one 8-layer pipeline stage of
+# mistral-nemo-12b.  Params + pool + temps fit the 16 GB chip with room
+# to spare.
+@pytest.mark.parametrize("arch,layers,n_slots,cache_len,peak", [
+    ("olmo-1b", None, 8, 1024, 8e9),
+    ("olmo-1b", None, 16, 2048, 8e9),
+    ("mistral-nemo-12b", 8, 16, 4096, 12e9),
+], ids=["olmo-1b-8x1024", "olmo-1b-16x2048", "mistral-nemo-12b-s8-16x4096"])
+def test_paged_decode_cell_compiles_with_kernel(one_chip, arch, layers,
+                                                n_slots, cache_len, peak):
+    """The engine's decode program at published widths, 16-token pages.
+    On this CPU the policy's auto dispatch would pick the XLA twin, so the
+    test steers it to the compiled kernel.  The pool rides the layer scan
+    in place: the program's scratch stays under one layer's share of the
+    pool, and no copy or slice of the pool (or of one layer of it) is
+    left."""
     from repro.models import registry
+    from repro.serve import paged
     from repro.serve.step import make_paged_cells
 
-    cfg = all_archs()["olmo-1b"]
-    n_slots, cache_len, block = 8, 1024, 16
+    cfg = all_archs()[arch]
+    if layers is not None:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    block = 16
     n_pages = n_slots * cache_len // block + 1
 
     def place(tree):
@@ -138,6 +174,12 @@ def test_olmo_1b_paged_decode_cell_compiles_with_kernel(one_chip):
             place(jax.eval_shape(cells.init_pool)),
             _sds(one_chip, (n_slots, cells.max_pages), jnp.int32),
         ).compile()
-    assert KERNEL in compiled.as_text()
-    # params + pool + temps fit the 16 GB chip with room to spare
-    assert compiled.memory_analysis().peak_memory_in_bytes < 8e9
+    text = compiled.as_text()
+    assert KERNEL in text
+    mem = compiled.memory_analysis()
+    assert mem.peak_memory_in_bytes < peak
+    pool_bytes = paged.pool_geometry(cfg, n_pages, block)["pool_bytes"]
+    assert mem.temp_size_in_bytes < pool_bytes / cfg.num_groups(), \
+        (mem.temp_size_in_bytes, pool_bytes)
+    page_dims = (n_pages, block, 2 * cfg.num_kv_heads, cfg.hd)
+    assert _pool_passes(text, page_dims) == []
