@@ -7,7 +7,7 @@ from repro.configs import (  # noqa: F401
     jamba_1_5_large_398b,
     rwkv6_7b,
     qwen3_moe_235b_a22b,
-    moonshot_v1_16b_a3b,
+    moonlight_16b_a3b,
     whisper_base,
     internvl2_26b,
 )

@@ -29,7 +29,24 @@ class ArchConfig:
     experts_per_token: int = 0
     moe_every: int = 1             # MoE FFN on layers with (l % moe_every == moe_every - 1)
     shared_experts: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training dispatch only; serving drops none
+    router_scoring: str = "softmax"  # softmax | sigmoid (DeepSeek-V3 noaux_tc:
+    #                                  a bias picks the experts, the unbiased
+    #                                  scores of those weigh them)
+    routed_scaling_factor: float = 1.0  # multiplies the normalised gates
+    # --- leading dense layers (DeepSeek-V3 first_k_dense_replace): a dense
+    #     MLP of width dense_d_ff (the published intermediate_size) in place
+    #     of the experts, held outside the scan over the MoE layers ---
+    first_k_dense_replace: int = 0
+    dense_d_ff: int = 0
+    # --- latent attention (MLA; kv_lora_rank > 0 selects it): q projects to
+    #     heads of qk_nope_head_dim + qk_rope_head_dim; keys and values come
+    #     from one cached row per token, a normed kv_lora_rank latent plus a
+    #     qk_rope_head_dim rotary key shared by every head ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- hybrid (Jamba): 1 attention layer per attn_period, rest Mamba ---
     attn_period: int = 0           # 0 = every layer is attention
     # --- SSM (Mamba) ---
@@ -44,6 +61,7 @@ class ArchConfig:
     rope_theta: float = 1_000_000.0
     # --- misc arch ---
     norm: str = "rmsnorm"          # rmsnorm | ln_nonparam
+    norm_eps: float = 1e-6
     act: str = "swiglu"            # swiglu | gelu | relu2
     tie_embeddings: bool = True
     use_bias: bool = False
@@ -67,6 +85,10 @@ class ArchConfig:
         return self.head_dim or (self.d_model // self.num_heads)
 
     @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
     def attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -76,8 +98,10 @@ class ArchConfig:
         return self.family in ("ssm", "hybrid") or self.sliding_window > 0
 
     def num_groups(self) -> int:
-        assert self.num_layers % max(self.layer_group, 1) == 0, self.name
-        return self.num_layers // max(self.layer_group, 1)
+        """Scanned layer groups: the layers after the leading dense ones."""
+        n = self.num_layers - self.first_k_dense_replace
+        assert n % max(self.layer_group, 1) == 0, self.name
+        return n // max(self.layer_group, 1)
 
     def is_attn_layer(self, l: int) -> bool:
         if self.family == "ssm":
@@ -119,7 +143,8 @@ def live_shapes(cfg: ArchConfig):
 def smoke(cfg: ArchConfig, seq: int = 32) -> ArchConfig:
     """Reduced same-family config for CPU smoke tests (tiny dims, same topology)."""
     group = 2 if cfg.layer_group > 1 else 1
-    n_layers = 2 * max(group, cfg.attn_period or 1, cfg.moe_every)
+    n_dense = min(cfg.first_k_dense_replace, 1)
+    n_layers = n_dense + 2 * max(group, cfg.attn_period or 1, cfg.moe_every)
     kv = max(1, min(2, cfg.num_kv_heads))
     return replace(
         cfg,
@@ -142,6 +167,14 @@ def smoke(cfg: ArchConfig, seq: int = 32) -> ArchConfig:
         num_patches=4 if cfg.num_patches else 0,
         layer_group=group,
         attn_period=min(cfg.attn_period, 4) if cfg.attn_period else 0,
+        first_k_dense_replace=n_dense,
+        dense_d_ff=128 if cfg.dense_d_ff else 0,
+        # latent, nope, rope and value widths all distinct, so a slice
+        # taken at the wrong width fails the tests
+        kv_lora_rank=32 if cfg.mla else 0,
+        qk_nope_head_dim=16 if cfg.mla else 0,
+        qk_rope_head_dim=8 if cfg.mla else 0,
+        v_head_dim=12 if cfg.mla else 0,
         remat="none",
     )
 

@@ -18,8 +18,10 @@ import jax
 import jax.numpy as jnp
 
 from repro import runtime
+from repro.kernels import expert_gmm as _eg
 from repro.kernels import flash_attention as _fa
 from repro.kernels import paged_attention as _pa
+from repro.kernels import paged_mla_attention as _pm
 from repro.kernels import quant as _q
 from repro.kernels import ref as _ref
 from repro.kernels import rwkv6_scan as _rs
@@ -109,6 +111,47 @@ def paged_attention(q, pool, tables, lengths, layer=0, *,
                             buffer_depth=buffer_depth,
                             use_kernel=use_paged_kernel(),
                             interpret=_interp())
+
+
+@partial(jax.jit, static_argnames=("latent", "sm_scale", "buffer_depth",
+                                   "use_kernel", "interpret"))
+def _paged_mla_attention(q, pool, tables, lengths, layer, *, latent,
+                         sm_scale, buffer_depth, use_kernel, interpret):
+    if use_kernel:
+        return _pm.paged_mla_attention_fwd(
+            q, pool, tables, lengths, layer, latent=latent,
+            sm_scale=sm_scale, buffer_depth=buffer_depth,
+            interpret=interpret)
+    return _pm.paged_mla_attention_xla(q, pool, tables, lengths, layer,
+                                       latent=latent, sm_scale=sm_scale,
+                                       buffer_depth=buffer_depth)
+
+
+def paged_mla_attention(q, pool, tables, lengths, layer=0, *, latent,
+                        sm_scale, buffer_depth=None):
+    """Policy-dispatched paged latent attention over ``pool``'s layer
+    ``layer`` (``kernels/paged_mla_attention.py``): the Pallas kernel or
+    its XLA twin, picked as ``paged_attention`` picks."""
+    if buffer_depth is None:
+        buffer_depth = int(runtime.policy()["paged_buffer_depth"])
+    return _paged_mla_attention(q, pool, tables, lengths, layer,
+                                latent=latent, sm_scale=float(sm_scale),
+                                buffer_depth=buffer_depth,
+                                use_kernel=use_paged_kernel(),
+                                interpret=_interp())
+
+
+def expert_gmm(lhs, rhs, group_sizes, layer=None, *, out_dtype=jnp.float32):
+    """Rows sorted by expert times their expert's weights
+    (``kernels/expert_gmm.py``): the Pallas kernel where it compiles
+    (the policy's ``pallas_interpret`` resolves to compiled), else its
+    XLA twin. ``rhs`` is (E, k, n), or (L, E, k, n)
+    read at ``layer``. Rows past ``sum(group_sizes)`` are unspecified."""
+    if _interp():
+        return _eg.expert_gmm_xla(lhs, rhs, group_sizes, layer,
+                                  out_dtype=out_dtype)
+    return _eg.expert_gmm_fwd(lhs, rhs, group_sizes, layer,
+                              out_dtype=out_dtype, interpret=False)
 
 
 # NOTE: unlike the attention/rwkv wrappers these are deliberately NOT

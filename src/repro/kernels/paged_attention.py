@@ -46,8 +46,14 @@ from repro.kernels.quant import resolve_interpret
 NEG_INF = -1e30
 
 
-def _decode_kernel(tables, lengths, layer, q_ref, pool, o_ref, buf, sem, *,
-                   page_size, depth, max_pages, n_kv, rep, sm_scale):
+def walk_pages(tables, lengths, layer, pool, buf, sem, *, page_size, depth,
+               max_pages, rows, width, scores, values):
+    """One program's walk over sequence ``program_id(0)``'s pages: the
+    DMA ring, the ragged mask and the online softmax every paged decode
+    kernel shares. ``scores(page)`` gives the (rows, page_size) scores of
+    a float32 page, ``values(p, page)`` the (rows, width) weighted values
+    for its probabilities ``p``. Returns the (rows, width) float32
+    attention output."""
     s = pl.program_id(0)
     length = lengths[s]
     n_pages = jax.lax.div(length + page_size - 1, page_size)
@@ -62,19 +68,12 @@ def _decode_kernel(tables, lengths, layer, q_ref, pool, o_ref, buf, sem, *,
         def _start(d=d):
             dma(d, d).start()
 
-    H, hd = q_ref.shape
-    qh = (q_ref[...].astype(jnp.float32) * sm_scale).reshape(n_kv, rep, hd)
-
     def body(j, carry):
         acc, m, l = carry
         slot = jax.lax.rem(j, depth)
         dma(j, slot).wait()
-        kv = buf[slot].astype(jnp.float32).reshape(page_size, n_kv, 2, hd)
-        k, v = kv[:, :, 0, :], kv[:, :, 1, :]
-        sc = jnp.concatenate(
-            [jax.lax.dot_general(qh[g], k[:, g], (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-             for g in range(n_kv)], axis=0)                   # (H, ps)
+        page = buf[slot].astype(jnp.float32)
+        sc = scores(page)
         pos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
         mask = pos < length          # ragged tail: pad positions masked
@@ -83,11 +82,7 @@ def _decode_kernel(tables, lengths, layer, q_ref, pool, o_ref, buf, sem, *,
         alpha = jnp.exp(m - m_new)
         p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
         l_new = l * alpha + jnp.sum(p, -1, keepdims=True)
-        ph = p.reshape(n_kv, rep, page_size)
-        onew = jnp.concatenate(
-            [jax.lax.dot_general(ph[g], v[:, g], (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-             for g in range(n_kv)], axis=0)                   # (H, hd)
+        onew = values(p, page)
         # refill this slot only after page j's compute consumed it — with
         # depth >= 2 the other depth-1 slots' DMAs are already in flight
         # behind this compute, which is the overlap the sweep measures
@@ -96,18 +91,72 @@ def _decode_kernel(tables, lengths, layer, q_ref, pool, o_ref, buf, sem, *,
             dma(j + depth, slot).start()
         return acc * alpha + onew, m_new, l_new
 
-    acc0 = jnp.zeros((H, hd), jnp.float32)
-    m0 = jnp.full((H, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((H, 1), jnp.float32)
+    acc0 = jnp.zeros((rows, width), jnp.float32)
+    m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((rows, 1), jnp.float32)
     acc, _, l = jax.lax.fori_loop(0, n_pages, body, (acc0, m0, l0))
-    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    return acc / jnp.maximum(l, 1e-30)
 
 
-def _stacked(pool, layer):
-    """A 4-D pool as the one layer of a stacked pool (a free reshape),
-    and ``layer`` as an int32 scalar: traced, so one program serves
-    every layer."""
-    if pool.ndim == 4:
+def paged_call(kern, q, pool, tables, lengths, layer, *, depth, width, name,
+               interpret):
+    """``kern`` over a grid of one program per sequence: the block
+    tables, lengths and layer prefetched as scalars, ``q``'s row and the
+    (S, H, ``width``) output's blocked per sequence, the pool left in HBM
+    for the kernel's DMAs into a ring of ``depth`` page buffers. ``name``
+    names the call, so that a profile finds the kernel by it whatever
+    program calls it."""
+    S, H = q.shape[:2]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((None,) + q.shape[1:],
+                               lambda s, *_: (s, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],   # pool stays HBM
+        out_specs=pl.BlockSpec((None, H, width), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((depth,) + pool.shape[2:], pool.dtype),
+                        pltpu.SemaphoreType.DMA((depth,))],
+    )
+    return pl.pallas_call(
+        kern, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, width), q.dtype),
+        interpret=interpret, name=name,
+    )(tables, lengths, layer.reshape(1), q, pool)
+
+
+def _decode_kernel(tables, lengths, layer, q_ref, pool, o_ref, buf, sem, *,
+                   page_size, depth, max_pages, n_kv, rep, sm_scale):
+    H, hd = q_ref.shape
+    qh = (q_ref[...].astype(jnp.float32) * sm_scale).reshape(n_kv, rep, hd)
+
+    def kv(page, half):
+        return page.reshape(page_size, n_kv, 2, hd)[:, :, half, :]
+
+    def scores(page):
+        k = kv(page, 0)
+        return jnp.concatenate(
+            [jax.lax.dot_general(qh[g], k[:, g], (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+             for g in range(n_kv)], axis=0)                   # (H, ps)
+
+    def values(p, page):
+        v, ph = kv(page, 1), p.reshape(n_kv, rep, page_size)
+        return jnp.concatenate(
+            [jax.lax.dot_general(ph[g], v[:, g], (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+             for g in range(n_kv)], axis=0)                   # (H, hd)
+
+    o_ref[...] = walk_pages(
+        tables, lengths, layer, pool, buf, sem, page_size=page_size,
+        depth=depth, max_pages=max_pages, rows=H, width=hd, scores=scores,
+        values=values).astype(o_ref.dtype)
+
+
+def _stacked(pool, layer, rank=4):
+    """A one-layer pool (``rank`` dims: 4 for K/V pages) as the one layer
+    of a stacked pool (a free reshape), and ``layer`` as an int32 scalar:
+    traced, so one program serves every layer."""
+    if pool.ndim == rank:
         pool = pool[None]
     return pool, jnp.asarray(layer, jnp.int32)
 
@@ -125,7 +174,7 @@ def paged_attention_fwd(q, pool, tables, lengths, layer=0, *, buffer_depth=2,
     page buffers kept in flight (static; clamped to [1, max_pages])."""
     interpret = resolve_interpret(interpret)
     pool, layer = _stacked(pool, layer)
-    S, H, hd = q.shape
+    _, H, hd = q.shape
     _, _, page_size, kv2, _ = pool.shape
     n_kv = kv2 // 2
     rep = H // n_kv
@@ -136,41 +185,21 @@ def paged_attention_fwd(q, pool, tables, lengths, layer=0, *, buffer_depth=2,
     kern = functools.partial(
         _decode_kernel, page_size=page_size, depth=depth,
         max_pages=max_pages, n_kv=n_kv, rep=rep, sm_scale=sm_scale)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S,),
-        in_specs=[pl.BlockSpec((None, H, hd), lambda s, *_: (s, 0, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],   # pool stays HBM
-        out_specs=pl.BlockSpec((None, H, hd), lambda s, *_: (s, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((depth, page_size, kv2, hd), pool.dtype),
-                        pltpu.SemaphoreType.DMA((depth,))],
-    )
-    # named, so that a profile finds the kernel by this name whatever
-    # program calls it
-    return pl.pallas_call(
-        kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, hd), q.dtype),
-        interpret=interpret, name="paged_attention",
-    )(tables, lengths, layer.reshape(1), q, pool)
+    return paged_call(kern, q, pool, tables, lengths, layer, depth=depth,
+                      width=hd, name="paged_attention", interpret=interpret)
 
 
-def paged_attention_xla(q, pool, tables, lengths, layer=0, *, buffer_depth=2,
-                        sm_scale=None):
-    """Pure-XLA twin of the kernel: scan over the block table in chunks
-    of ``buffer_depth`` pages (gathered together, folded into the same
-    online softmax).  Identical math and walk order; the depth knob here
-    amortizes per-page gather/dispatch overhead rather than overlapping
-    DMA, so the page-size x depth sweep stays observable on CPU.  The
-    pool and ``layer`` as for the kernel: each chunk gathers
-    ``pool[layer, pages]``."""
-    pool, layer = _stacked(pool, layer)
-    S, H, hd = q.shape
-    _, n_pages_tot, page_size, kv2, _ = pool.shape
-    n_kv = kv2 // 2
-    rep = H // n_kv
-    max_pages = tables.shape[1]
+def walk_pages_xla(pool, tables, lengths, layer, *, buffer_depth, scores,
+                   values, out_shape):
+    """The XLA twin of ``walk_pages`` for every sequence at once: a scan
+    over the block tables in chunks of ``buffer_depth`` pages, gathered
+    together and folded into the same online softmax. ``scores(chunk)``
+    gives (S, ..., T) scores of a float32 chunk (S, T, *page row), with T
+    its positions; ``values(p, chunk)`` the weighted values, shaped as
+    ``out_shape`` (S, ..., width). Returns the float32 output."""
+    S, max_pages = tables.shape
+    _, n_pages_tot, page_size = pool.shape[:3]
     depth = max(1, min(buffer_depth, max_pages))
-    sm_scale = sm_scale if sm_scale is not None else hd ** -0.5
     n_chunks = -(-max_pages // depth)
     pad = n_chunks * depth - max_pages
     # pad ragged chunk tails with the trash page (id n_pages_tot - 1 by
@@ -179,28 +208,51 @@ def paged_attention_xla(q, pool, tables, lengths, layer=0, *, buffer_depth=2,
     tbl = tbl.reshape(S, n_chunks, depth).swapaxes(0, 1)    # (C, S, depth)
     pos = (jnp.arange(n_chunks * depth)[:, None] * page_size
            + jnp.arange(page_size)[None]).reshape(n_chunks, depth * page_size)
-    qh = q.reshape(S, n_kv, rep, hd).astype(jnp.float32) * sm_scale
+    lead = (S,) + (1,) * (len(out_shape) - 2)
 
     def body(carry, inp):
         acc, m, l = carry
         tbl_c, pos_c = inp
-        kv = pool[layer, tbl_c].astype(jnp.float32).reshape(
-            S, depth * page_size, n_kv, 2, hd)
-        k, v = kv[..., 0, :], kv[..., 1, :]
-        sc = jnp.einsum("sgrh,stgh->sgrt", qh, k)           # (S,Kv,rep,T)
-        mask = pos_c[None] < lengths[:, None]               # (S, T)
-        sc = jnp.where(mask[:, None, None], sc, NEG_INF)
+        chunk = pool[layer, tbl_c].astype(jnp.float32).reshape(
+            (S, depth * page_size) + pool.shape[3:])
+        sc = scores(chunk)
+        mask = (pos_c[None] < lengths[:, None]).reshape(lead + (-1,))
+        sc = jnp.where(mask, sc, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sc, -1))
         alpha = jnp.exp(m - m_new)
-        p = jnp.where(mask[:, None, None],
-                      jnp.exp(sc - m_new[..., None]), 0.0)
+        p = jnp.where(mask, jnp.exp(sc - m_new[..., None]), 0.0)
         l_new = l * alpha + jnp.sum(p, -1)
-        acc_new = acc * alpha[..., None] + jnp.einsum("sgrt,stgh->sgrh", p, v)
+        acc_new = acc * alpha[..., None] + values(p, chunk)
         return (acc_new, m_new, l_new), None
 
-    acc0 = jnp.zeros((S, n_kv, rep, hd), jnp.float32)
-    m0 = jnp.full((S, n_kv, rep), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((S, n_kv, rep), jnp.float32)
+    acc0 = jnp.zeros(out_shape, jnp.float32)
+    m0 = jnp.full(out_shape[:-1], NEG_INF, jnp.float32)
+    l0 = jnp.zeros(out_shape[:-1], jnp.float32)
     (acc, _, l), _ = jax.lax.scan(body, (acc0, m0, l0), (tbl, pos))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return acc / jnp.maximum(l, 1e-30)[..., None]
+
+
+def paged_attention_xla(q, pool, tables, lengths, layer=0, *, buffer_depth=2,
+                        sm_scale=None):
+    """Pure-XLA twin of the kernel (``walk_pages_xla``): scan over the
+    block table in chunks of ``buffer_depth`` pages.  Identical math and
+    walk order; the depth knob here amortizes per-page gather/dispatch
+    overhead rather than overlapping DMA, so the page-size x depth sweep
+    stays observable on CPU.  The pool and ``layer`` as for the kernel:
+    each chunk gathers ``pool[layer, pages]``."""
+    pool, layer = _stacked(pool, layer)
+    S, H, hd = q.shape
+    n_kv = pool.shape[3] // 2
+    rep = H // n_kv
+    sm_scale = sm_scale if sm_scale is not None else hd ** -0.5
+    qh = q.reshape(S, n_kv, rep, hd).astype(jnp.float32) * sm_scale
+
+    def kv(chunk, half):
+        return chunk.reshape(chunk.shape[:2] + (n_kv, 2, hd))[..., half, :]
+
+    out = walk_pages_xla(
+        pool, tables, lengths, layer, buffer_depth=buffer_depth,
+        scores=lambda c: jnp.einsum("sgrh,stgh->sgrt", qh, kv(c, 0)),
+        values=lambda p, c: jnp.einsum("sgrt,stgh->sgrh", p, kv(c, 1)),
+        out_shape=(S, n_kv, rep, hd))
     return out.reshape(S, H, hd).astype(q.dtype)

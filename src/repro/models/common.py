@@ -61,11 +61,12 @@ def norm_init(cfg: ArchConfig, dim: Optional[int] = None) -> dict:
 def norm_apply(cfg: ArchConfig, p: dict, x: jax.Array) -> jax.Array:
     xf = x.astype(jnp.float32)
     if cfg.norm == "rmsnorm":
-        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + 1e-6)
+        y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                                 + cfg.norm_eps)
         return (y * p["scale"].astype(jnp.float32)).astype(x.dtype)
     mean = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
-    y = (xf - mean) * jax.lax.rsqrt(var + 1e-6)
+    y = (xf - mean) * jax.lax.rsqrt(var + cfg.norm_eps)
     if cfg.norm == "layernorm":
         y = y * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
     return y.astype(x.dtype)

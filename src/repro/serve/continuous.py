@@ -97,6 +97,11 @@ class StepEvent:
     admitted: tuple            # rids whose prefill ran this iteration
     decoded: tuple             # rids advanced by this iteration's decode step
     queued: int                # requests still waiting after admission
+    # MoE paged decode only (None otherwise): mean distinct experts the
+    # step's live tokens hit per MoE layer, and the most tokens any one
+    # expert took in any layer
+    experts_hit: Optional[float] = None
+    expert_load_max: Optional[int] = None
 
 
 class ContinuousEngine:
@@ -363,20 +368,28 @@ class ContinuousEngine:
                          condition=self.fabric.condition.name)
                 tr.end("engine", t=self._T(t_start + stall_s),
                        stalled_s=stall_s)
+        load = None
         with annotate("serve.decode", step=step, active=len(active)):
             if self.paged:
-                logits, self._pool = self._decode(
+                logits, self._pool, *moe_load = self._decode(
                     self.params, jnp.asarray(self._tok)[:, None],
                     jnp.asarray(self._idx), self._pool, self._tables_dev)
+                load = moe_load[0] if moe_load else None
             else:
                 logits, self._caches = self._decode(
                     self.params, jnp.asarray(self._tok)[:, None, None],
                     jnp.asarray(self._idx), self._caches)
         with annotate("serve.sample", step=step):
             last = logits[:, 0] if self.paged else logits[:, 0, -1]
-            nxt = np.asarray(jnp.argmax(last, axis=-1))     # host sync
+            nxt = jnp.argmax(last, axis=-1)
+            if load is None:
+                nxt = np.asarray(nxt)                       # host sync
+            else:
+                # the expert counts ride the tokens' transfer
+                nxt, load = jax.device_get((nxt, load))
         now = self.clock() - self._t0
-        return active, nxt, t_start, now, max(now, t_start + stall_s)
+        return (active, nxt, t_start, now, max(now, t_start + stall_s),
+                load)
 
     def _book(self, active, nxt, t_start: float, now: float,
               t_end: float) -> list[int]:
@@ -418,6 +431,15 @@ class ContinuousEngine:
             self._slot_open[slot] = False
         if self.debug:
             self.kv.check()
+
+    def _kv_bytes(self) -> dict:
+        """Bytes of the pages in use, by what they hold (paged only):
+        ``{"kv_bytes": ...}`` or ``{"latent_bytes": ...}``."""
+        if not self.paged:
+            return {}
+        from repro.serve.paged import pool_geometry
+        g = pool_geometry(self.cfg, self.kv.n_pages, self.kv.block_size)
+        return {f"{g['state_kind']}_bytes": g["page_bytes"] * self.kv.n_used}
 
     # -- run loop ----------------------------------------------------------
 
@@ -515,7 +537,13 @@ class ContinuousEngine:
                         time.sleep(self.IDLE_SLEEP_S)
                 continue
             with annotate("serve.book", step=step):
-                decoded = self._book(*sampled) if sampled else []
+                decoded = self._book(*sampled[:-1]) if sampled else []
+                load = sampled[-1] if sampled else None
+                hit = load_max = None
+                if load is not None:
+                    per_layer = load.reshape(-1, load.shape[-1])
+                    hit = float(np.mean(np.count_nonzero(per_layer, -1)))
+                    load_max = int(load.max())
                 if tr.enabled:
                     # per-iteration pool/queue watermarks, each on its own
                     # counter track (timestamps are this iteration's
@@ -525,12 +553,17 @@ class ContinuousEngine:
                     tr.counter("slots", "slot_occupancy", t=self._T(now),
                                active=self.scheduler.n_active)
                     tr.counter("kv", "kv_pages", t=self._T(now),
-                               free=self.kv.n_free, used=self.kv.n_used)
+                               free=self.kv.n_free, used=self.kv.n_used,
+                               **self._kv_bytes())
+                    if load is not None:
+                        tr.counter("moe", "expert_load", t=self._T(now),
+                                   experts_hit=hit, load_max=load_max)
                     tr.metrics.count("work_iters")
                 self.step_log.append(StepEvent(
                     now=now, admitted=tuple(admitted),
                     decoded=tuple(decoded),
-                    queued=len(self.scheduler.pending)))
+                    queued=len(self.scheduler.pending),
+                    experts_hit=hit, expert_load_max=load_max))
         if tr.enabled:
             # a still-open merged idle span (the loop drained while idle)
             # closes at the last loop-top time seen

@@ -12,7 +12,9 @@ the ``serve.load_sweep`` characterization wants observable.
 Blocks are *physical* in the paged engine (DESIGN.md section 14): block
 id ``b`` names page ``b`` of the preallocated ``[n_pages, block_size,
 2*n_kv_heads, head_dim]`` pool tensor ``serve/paged.py`` materializes per
-attention layer, so the table this allocator hands out is exactly the
+attention layer (``[n_pages, block_size, row]`` latent rows for latent
+attention: the allocator counts positions, whatever a page holds), so
+the table this allocator hands out is exactly the
 page indirection the ragged paged-attention kernel walks.  One extra
 *trash page* (id ``n_blocks``) sits past the allocatable pool: device
 block tables are fixed-width, and rows are padded with the trash id so

@@ -241,6 +241,7 @@ class PagedServeCells:
     buffer_depth: int
     prefill: Callable        # (params, tokens[1,S]) -> (logits, base caches)
     decode: Callable         # (params, tok[S,1], idx[S], pool, tables[S,mp])
+    #                          -> (logits, pool[, expert load])
     insert: Callable         # (pool, base caches, table_row[mp]) -> pool
     mesh: Optional[object] = None
     ctx: Optional[sharding.ShardingCtx] = None
@@ -307,7 +308,9 @@ def make_paged_cells(cfg: ArchConfig, n_slots: int, cache_len: int,
     """
     from repro.serve import paged
 
-    paged.check_paged(cfg, cache_len, block_size)
+    paged.check_paged(cfg, cache_len, block_size,
+                      tp_size=1 if mesh is None
+                      else int(dict(mesh.shape).get("model", 1)))
 
     def serve_prefill(params, tokens):
         return registry.prefill(cfg, params, {"tokens": tokens},

@@ -13,7 +13,7 @@ EXPECTED = {
     "jamba-1.5-large-398b": (72, 8192, 64, 8, 24576, 65536),
     "rwkv6-7b": (32, 4096, 64, 64, 14336, 65536),
     "qwen3-moe-235b-a22b": (94, 4096, 64, 4, 1536, 151936),
-    "moonshot-v1-16b-a3b": (48, 2048, 16, 16, 1408, 163840),
+    "moonlight-16b-a3b": (27, 2048, 16, 16, 1408, 163840),
     "whisper-base": (6, 512, 8, 8, 2048, 51865),
     "internvl2-26b": (48, 6144, 48, 8, 16384, 92553),
 }
@@ -33,8 +33,17 @@ def test_exact_dims(name):
 def test_moe_configs():
     q = all_archs()["qwen3-moe-235b-a22b"]
     assert (q.num_experts, q.experts_per_token) == (128, 8)
-    m = all_archs()["moonshot-v1-16b-a3b"]
+    m = all_archs()["moonlight-16b-a3b"]
     assert (m.num_experts, m.experts_per_token, m.shared_experts) == (64, 6, 2)
+    assert (m.kv_lora_rank, m.qk_nope_head_dim, m.qk_rope_head_dim,
+            m.v_head_dim) == (512, 128, 64, 128)
+    assert (m.first_k_dense_replace, m.dense_d_ff) == (1, 11264)
+    assert (m.router_scoring, m.routed_scaling_factor) == ("sigmoid", 2.446)
+    assert (m.tie_embeddings, m.rope_theta, m.norm_eps) == (False, 5e4, 1e-5)
+    assert m.num_groups() == 26
+    s = smoke(m)
+    assert s.first_k_dense_replace == 1 and s.num_groups() >= 2
+    assert s.kv_lora_rank != s.qk_rope_head_dim
     j = all_archs()["jamba-1.5-large-398b"]
     assert (j.num_experts, j.experts_per_token, j.attn_period) == (16, 2, 8)
 

@@ -318,3 +318,130 @@ def test_quant_kernel_matches_ref(N, C):
     q2, s2 = ref.quantize_int8_ref(x)
     assert (q1 == q2).all() and jnp.allclose(s1, s2)
     assert jnp.max(jnp.abs(xd - x)) <= float(jnp.max(s1)) + 1e-6
+
+
+def _mla_case(seed, L, S, H, W, latent, ps, max_pages, lengths):
+    """A stacked latent pool (L, n_pages, ps, W) with trash-padded tables
+    over ragged lengths, and absorbed queries (S, H, W)."""
+    import numpy as np
+    rng = np.random.default_rng(seed)
+    n_blocks = S * max_pages
+    q = jnp.asarray(rng.standard_normal((S, H, W)), jnp.float32)
+    pool = jnp.asarray(rng.standard_normal((L, n_blocks + 1, ps, W)),
+                       jnp.float32)
+    perm = rng.permutation(n_blocks)
+    tables = np.full((S, max_pages), n_blocks, np.int32)
+    k = 0
+    for s, n in enumerate(lengths):
+        need = -(-n // ps)
+        tables[s, :need] = perm[k:k + need]
+        k += need
+    return q, pool, jnp.asarray(tables), jnp.asarray(lengths, jnp.int32)
+
+
+def _mla_oracle(q, pool, tables, lengths, latent, scale):
+    """Full softmax over each sequence's gathered rows."""
+    import numpy as np
+    out = []
+    for s in range(q.shape[0]):
+        n = int(lengths[s])
+        rows = pool[np.asarray(tables[s])].reshape(-1, pool.shape[-1])[:n]
+        p = jax.nn.softmax(q[s] @ rows.T * scale, -1)
+        out.append(p @ rows[:, :latent])
+    return jnp.stack(out)
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3])
+@pytest.mark.parametrize("layer", [0, 2])
+def test_paged_mla_attention_kernel_matches_twin_and_oracle(depth, layer):
+    """The latent kernel (interpret) and its XLA twin, handed a stacked
+    pool and a traced layer index, match a full softmax over that layer's
+    rows — scores against the whole row, output over its latent prefix —
+    on ragged lengths (a page-aligned one among them) with trash-padded
+    tables."""
+    from repro.kernels import paged_mla_attention as pm
+    lengths = (1, 13, 40, 48)
+    q, pool, tables, lens = _mla_case(41, 3, 4, 4, 128, 40, 8, 6, lengths)
+    scale = 24 ** -0.5
+    want = _mla_oracle(q, pool[layer], tables, lens, 40, scale)
+    got_k = jax.jit(lambda l: pm.paged_mla_attention_fwd(
+        q, pool, tables, lens, l, latent=40, sm_scale=scale,
+        buffer_depth=depth, interpret=True))(layer)
+    got_x = jax.jit(lambda l: pm.paged_mla_attention_xla(
+        q, pool, tables, lens, l, latent=40, sm_scale=scale,
+        buffer_depth=depth))(layer)
+    assert got_k.shape == (4, 4, 40)
+    assert jnp.max(jnp.abs(got_k - want)) < 2e-5
+    assert jnp.max(jnp.abs(got_x - got_k)) < 2e-5
+
+
+def test_paged_mla_attention_ignores_trash_and_pad_positions():
+    """The trash page, unowned pages, past-length tails and the other
+    layers poisoned: the kernel's output does not move."""
+    import numpy as np
+    from repro.kernels import paged_mla_attention as pm
+    lengths = (5, 17, 26)
+    q, pool, tables, lens = _mla_case(43, 2, 3, 4, 128, 40, 8, 4, lengths)
+    kw = dict(latent=40, sm_scale=0.2, buffer_depth=2, interpret=True)
+    base = pm.paged_mla_attention_fwd(q, pool, tables, lens, 1, **kw)
+    tbl = np.asarray(tables)
+    owned = {int(p) for s, n in enumerate(lengths)
+             for p in tbl[s, :-(-n // 8)]}
+    poisoned = np.array(pool)
+    poisoned[0] = 1e6                                  # the other layer
+    for p in range(poisoned.shape[1]):
+        if p not in owned:
+            poisoned[1, p] = 1e6                       # trash + unowned
+    for s, n in enumerate(lengths):
+        poisoned[1, tbl[s, (n - 1) // 8], n % 8 or 8:] = 1e6
+    got = pm.paged_mla_attention_fwd(q, jnp.asarray(poisoned), tables, lens,
+                                     1, **kw)
+    assert jnp.max(jnp.abs(got - base)) == 0.0
+
+
+@pytest.mark.parametrize("sizes", [
+    (3, 0, 5, 1, 0, 7, 2, 0),          # empty experts, groups across tiles
+    (16, 16, 0, 0, 0, 0, 0, 0),        # tile-aligned groups
+    (0, 0, 0, 0, 0, 0, 0, 29),         # one expert takes every row
+])
+@pytest.mark.parametrize("layer", [None, 1])
+def test_expert_gmm_matches_per_expert_loop(sizes, layer):
+    """The grouped matmul (interpret) and its XLA twin against a plain
+    loop over experts, each multiplying its own rows, on weights stacked
+    over layers (read at a traced index) or one layer's; rows past the
+    groups (here 3 more) are the caller's to mask."""
+    import numpy as np
+    from repro.kernels import expert_gmm as eg
+    rng = np.random.default_rng(7)
+    E, k, n = len(sizes), 64, 96
+    m = sum(sizes) + 3
+    lhs = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((2, E, k, n)), jnp.float32)
+    w = rhs if layer is not None else rhs[0]
+    gs = jnp.asarray(sizes, jnp.int32)
+    want, start = np.zeros((sum(sizes), n), np.float32), 0
+    for e, size in enumerate(sizes):
+        want[start:start + size] = np.asarray(
+            lhs[start:start + size] @ rhs[layer or 0, e])
+        start += size
+    if layer is None:
+        got_k = eg.expert_gmm_fwd(lhs, w, gs, interpret=True)
+        got_x = eg.expert_gmm_xla(lhs, w, gs)
+    else:
+        got_k = jax.jit(lambda l: eg.expert_gmm_fwd(
+            lhs, w, gs, l, interpret=True))(layer)
+        got_x = jax.jit(lambda l: eg.expert_gmm_xla(lhs, w, gs, l))(layer)
+    assert got_k.shape == (m, n)
+    assert jnp.max(jnp.abs(got_k[:sum(sizes)] - want)) < 1e-4
+    assert jnp.max(jnp.abs(got_x[:sum(sizes)] - want)) < 1e-4
+
+
+def test_expert_gmm_tiles():
+    """Decode's few rows take 16-row tiles, a prefill's many 128; the n
+    tile is the widest whose weight block fits 8 MiB (the whole expert at
+    Moonlight's widths)."""
+    from repro.kernels import expert_gmm as eg
+    assert eg.row_tile(192) == 16 and eg.row_tile(3072) == 128
+    assert eg.col_tile(2048, 1408, 2) == 1408
+    assert eg.col_tile(1408, 2048, 2) == 2048
+    assert eg.col_tile(4096, 4096, 2) == 1024

@@ -24,8 +24,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import runtime
 from repro.configs import all_archs
+from repro.kernels import expert_gmm as eg
 from repro.kernels import flash_attention as fa
 from repro.kernels import paged_attention as pa
+from repro.kernels import paged_mla_attention as pm
 from repro.kernels import quant
 from repro.kernels import rwkv6_scan as rs
 
@@ -75,6 +77,36 @@ def test_paged_attention_compiles_at_olmo_widths(one_chip):
     assert KERNEL in text
 
 
+def test_paged_mla_attention_compiles_at_moonlight_widths(one_chip):
+    # Moonlight-16B-A3B: 16 heads over 512 + 64 latent rows padded to 640
+    # lanes; 32 slots x 4096 positions in 16-token pages, 8 layers stacked
+    q = _sds(one_chip, (32, 16, 640), jnp.bfloat16)
+    pool = _sds(one_chip, (8, 8193, 16, 640), jnp.bfloat16)
+    tables = _sds(one_chip, (32, 256), jnp.int32)
+    lengths = _sds(one_chip, (32,), jnp.int32)
+    layer = _sds(one_chip, (), jnp.int32)
+    text = _compiled_text(
+        lambda *a: pm.paged_mla_attention_fwd(
+            *a, latent=512, sm_scale=192 ** -0.5, interpret=False),
+        q, pool, tables, lengths, layer)
+    assert KERNEL in text
+
+
+# (rows, k, n): a 32-slot decode step's 32 x 6 token-expert rows and a
+# 512-token prefill's, through the gate/up (2048 -> 1408) and down
+# (1408 -> 2048) projections of Moonlight's 64 experts, stacked over 8
+# layers and read at a traced layer
+@pytest.mark.parametrize("m,k,n", [(192, 2048, 1408), (192, 1408, 2048),
+                                   (3072, 2048, 1408), (3072, 1408, 2048)])
+def test_expert_gmm_compiles_at_moonlight_widths(one_chip, m, k, n):
+    text = _compiled_text(
+        lambda a, b, g, l: eg.expert_gmm_fwd(a, b, g, l, interpret=False),
+        _sds(one_chip, (m, k), jnp.bfloat16),
+        _sds(one_chip, (8, 64, k, n), jnp.bfloat16),
+        _sds(one_chip, (64,), jnp.int32), _sds(one_chip, (), jnp.int32))
+    assert KERNEL in text
+
+
 def test_flash_attention_compiles_at_olmo_widths(one_chip):
     x = _sds(one_chip, (1, 512, 16, 128), jnp.bfloat16)
     text = _compiled_text(
@@ -115,8 +147,10 @@ def test_rwkv6_scan_compiles_at_rwkv6_7b_widths(one_chip):
 
 def _pool_passes(text: str, page_dims: tuple) -> list[str]:
     """Copies and dynamic slices in compiled HLO ``text`` whose result
-    ends in the pool's page geometry ``(n_pages, bs, 2*Kv, hd)``: the
-    whole stacked pool, one layer of it, or one layer with a unit axis."""
+    ends in ``page_dims``: for the pool's page geometry ``(n_pages, bs,
+    *row)``, the whole stacked pool, one layer of it, or one layer with a
+    unit axis; for an expert kernel's ``(E, k, n)``, one layer's experts
+    copied out of the stacked weights."""
     op = re.compile(r"^\s*(?:ROOT )?%([\w.-]+) = \w+\[([\d,]*)\]\S* "
                     r"([\w-]+)\(")
     hits = []
@@ -126,21 +160,23 @@ def _pool_passes(text: str, page_dims: tuple) -> list[str]:
                                   m.group(1) + " " + m.group(3)):
             continue
         dims = tuple(int(d) for d in m.group(2).split(",") if d)
-        if dims[-4:] == page_dims:
+        if dims[-len(page_dims):] == page_dims:
             hits.append(line.strip()[:160])
     return hits
 
 
 # (arch, layers kept, slots, positions, peak bound): chip_smoke.py's
-# engine, and the benchmark's two cells (perfbench/configs): olmo-1b at
-# the published context and one 8-layer pipeline stage of
-# mistral-nemo-12b.  Params + pool + temps fit the 16 GB chip with room
-# to spare.
+# engine, and the benchmark's cells (perfbench/configs): olmo-1b at the
+# published context, one 8-layer pipeline stage of mistral-nemo-12b and
+# the first 9-layer stage of Moonlight-16B-A3B (latent pages, 64 experts
+# a layer).  Params + pool + temps fit the 16 GB chip.
 @pytest.mark.parametrize("arch,layers,n_slots,cache_len,peak", [
     ("olmo-1b", None, 8, 1024, 8e9),
     ("olmo-1b", None, 16, 2048, 8e9),
     ("mistral-nemo-12b", 8, 16, 4096, 12e9),
-], ids=["olmo-1b-8x1024", "olmo-1b-16x2048", "mistral-nemo-12b-s8-16x4096"])
+    ("moonlight-16b-a3b", 9, 32, 4096, 14.5e9),
+], ids=["olmo-1b-8x1024", "olmo-1b-16x2048", "mistral-nemo-12b-s8-16x4096",
+        "moonlight-16b-a3b-s9-32x4096"])
 def test_paged_decode_cell_compiles_with_kernel(one_chip, arch, layers,
                                                 n_slots, cache_len, peak):
     """The engine's decode program at published widths, 16-token pages.
@@ -181,5 +217,12 @@ def test_paged_decode_cell_compiles_with_kernel(one_chip, arch, layers,
     pool_bytes = paged.pool_geometry(cfg, n_pages, block)["pool_bytes"]
     assert mem.temp_size_in_bytes < pool_bytes / cfg.num_groups(), \
         (mem.temp_size_in_bytes, pool_bytes)
-    page_dims = (n_pages, block, 2 * cfg.num_kv_heads, cfg.hd)
+    page_dims = jax.eval_shape(cells.init_pool)["l0"].shape[1:]
     assert _pool_passes(text, page_dims) == []
+    if cfg.num_experts:
+        # the latent kernel and the grouped matmul run, named, and read
+        # each layer's experts in place
+        assert "%paged_mla_attention" in text and "%expert_gmm" in text
+        D, F, E = cfg.d_model, cfg.d_ff, cfg.num_experts
+        assert _pool_passes(text, (E, D, F)) == []
+        assert _pool_passes(text, (E, F, D)) == []
