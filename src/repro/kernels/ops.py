@@ -81,7 +81,7 @@ def _paged_attention(q, pool, tables, lengths, layer, *, buffer_depth,
     if use_kernel:
         return _pa.paged_attention_fwd(q, pool, tables, lengths, layer,
                                        buffer_depth=buffer_depth,
-                                       interpret=interpret)
+                                       interpret=interpret).astype(q.dtype)
     return _pa.paged_attention_xla(q, pool, tables, lengths, layer,
                                    buffer_depth=buffer_depth)
 
@@ -121,7 +121,7 @@ def _paged_mla_attention(q, pool, tables, lengths, layer, *, latent,
         return _pm.paged_mla_attention_fwd(
             q, pool, tables, lengths, layer, latent=latent,
             sm_scale=sm_scale, buffer_depth=buffer_depth,
-            interpret=interpret)
+            interpret=interpret).astype(q.dtype)
     return _pm.paged_mla_attention_xla(q, pool, tables, lengths, layer,
                                        latent=latent, sm_scale=sm_scale,
                                        buffer_depth=buffer_depth)

@@ -12,13 +12,17 @@ whole pool) before the call.  A 4-D pool ``(n_pages, page_size, 2*Kv,
 hd)`` is one layer, at index 0.  The kernel grid
 is one program per sequence; each program walks its block table (a
 scalar-prefetch array, so page ids are known before the DMAs they index)
-and keeps ``buffer_depth`` page copies in flight: pages ``j+1 ..
-j+depth-1`` stream HBM->VMEM while page ``j``'s scores fold into the
+a *block* of pages at a time — one page for this kernel, the fewest whole
+pages that give a contraction ``MIN_BLOCK_ROWS`` rows for the latent
+kernel (``pages_per_block``, from the page's shape) — and keeps
+``buffer_depth`` blocks in flight: blocks ``b+1 .. b+depth-1`` stream
+HBM->VMEM, one DMA per page, while block ``b``'s scores fold into the
 running online-softmax state — the paper's headroom-during-transfer
 question at kernel granularity (how much attention compute hides behind
-page fetches?).  The tail page is ragged: positions past ``lengths[s]``
-are masked, so sequences need not fill their last page, and table rows
-are padded with a trash page that is never read unmasked.
+page fetches?).  The tail is ragged: positions past ``lengths[s]`` are
+masked, pages past the last are never fetched, so sequences need not
+fill their last page or block, and table rows are padded with a trash
+page that is never read.  The kernels return the walk's float32 output.
 
 ``interpret=None`` resolves per backend exactly like ``kernels/quant.py``
 (compiled Mosaic on TPU/GPU, interpreter on CPU, where the DMA semantics
@@ -46,67 +50,111 @@ from repro.kernels.quant import resolve_interpret
 NEG_INF = -1e30
 
 
-def walk_pages(tables, lengths, layer, pool, buf, sem, *, page_size, depth,
-               max_pages, rows, width, scores, values):
+MIN_BLOCK_ROWS = 128         # rows a contraction should see: one MXU tile
+MAX_BLOCK_BYTES = 1 << 20    # VMEM for one ring slot's block of pages
+
+
+def pages_per_block(page_size, row_bytes, max_pages):
+    """Pages one loop iteration of a walk moves and contracts, from the
+    page's shape (``row_bytes``: one position's row of one layer): the
+    fewest whole pages holding ``MIN_BLOCK_ROWS`` rows, at most the
+    table's ``max_pages`` and as many as ``MAX_BLOCK_BYTES`` of VMEM
+    holds, and at least one."""
+    want = -(-MIN_BLOCK_ROWS // page_size)
+    fit = MAX_BLOCK_BYTES // (page_size * row_bytes)
+    return max(1, min(want, max_pages, fit))
+
+
+def walk_pages(tables, lengths, layer, pool, buf, sem, *, rows, width,
+               scores, values):
     """One program's walk over sequence ``program_id(0)``'s pages: the
     DMA ring, the ragged mask and the online softmax every paged decode
-    kernel shares. ``scores(page)`` gives the (rows, page_size) scores of
-    a float32 page, ``values(p, page)`` the (rows, width) weighted values
-    for its probabilities ``p``. Returns the (rows, width) float32
+    kernel shares. ``buf`` is the ring, (depth, pages a block, page_size,
+    *row): each iteration waits for one block's page copies, each page
+    fetched by its own DMA from the block table, and contracts the block
+    as one (block rows, *row) operand, as stored.
+    ``scores(block)`` gives the block's (rows, block rows) float32
+    scores, ``values(p, block)`` the (rows, width) weighted values for
+    their float32 probabilities ``p``. Returns the (rows, width) float32
     attention output."""
     s = pl.program_id(0)
     length = lengths[s]
+    depth, per_block, page_size = buf.shape[:3]
+    block_rows = per_block * page_size
     n_pages = jax.lax.div(length + page_size - 1, page_size)
+    n_blocks = jax.lax.div(n_pages + per_block - 1, per_block)
 
-    def dma(j, slot):
-        return pltpu.make_async_copy(pool.at[layer[0], tables[s, j]],
-                                     buf.at[slot], sem.at[slot])
+    def each_copy(b, slot, act):
+        # pages past the sequence's last are never fetched: their rows
+        # hold whatever the slot last held, cleared below before a sum
+        for i in range(per_block):
+            j = b * per_block + i
+
+            @pl.when(j < n_pages)
+            def _(i=i, j=j):
+                act(pltpu.make_async_copy(
+                    pool.at[layer[0], tables[s, j]], buf.at[slot, i],
+                    sem.at[slot]))
 
     # warm-up: fill the buffer ring before the first wait
-    for d in range(min(depth, max_pages)):
-        @pl.when(d < n_pages)
-        def _start(d=d):
-            dma(d, d).start()
+    for d in range(min(depth, -(-tables.shape[1] // per_block))):
+        each_copy(d, d, lambda c: c.start())
 
-    def body(j, carry):
+    def body(b, carry):
         acc, m, l = carry
-        slot = jax.lax.rem(j, depth)
-        dma(j, slot).wait()
-        page = buf[slot].astype(jnp.float32)
-        sc = scores(page)
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
+        slot = jax.lax.rem(b, depth)
+        each_copy(b, slot, lambda c: c.wait())
+
+        # the last block's rows past ``length`` (a ragged tail, pages
+        # never fetched) are zeroed in place: a masked probability is 0,
+        # and 0 x NaN would still poison the value sum
+        @pl.when(b == n_blocks - 1)
+        def _clear_tail():
+            shape = buf.shape[1:]
+            pos = (b * block_rows
+                   + jax.lax.broadcasted_iota(jnp.int32, shape, 0) * page_size
+                   + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+            buf[slot] = jnp.where(pos < length, buf[slot],
+                                  jnp.zeros((), buf.dtype))
+
+        block = buf[slot].reshape((block_rows,) + buf.shape[3:])
+        sc = scores(block)
+        pos = b * block_rows + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_rows), 1)
         mask = pos < length          # ragged tail: pad positions masked
         sc = jnp.where(mask, sc, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(sc, -1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
         l_new = l * alpha + jnp.sum(p, -1, keepdims=True)
-        onew = values(p, page)
-        # refill this slot only after page j's compute consumed it — with
+        onew = values(p, block)
+        # refill this slot only after block b's compute consumed it — with
         # depth >= 2 the other depth-1 slots' DMAs are already in flight
         # behind this compute, which is the overlap the sweep measures
-        @pl.when(j + depth < n_pages)
+        @pl.when(b + depth < n_blocks)
         def _next():
-            dma(j + depth, slot).start()
+            each_copy(b + depth, slot, lambda c: c.start())
         return acc * alpha + onew, m_new, l_new
 
     acc0 = jnp.zeros((rows, width), jnp.float32)
     m0 = jnp.full((rows, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((rows, 1), jnp.float32)
-    acc, _, l = jax.lax.fori_loop(0, n_pages, body, (acc0, m0, l0))
+    acc, _, l = jax.lax.fori_loop(0, n_blocks, body, (acc0, m0, l0))
     return acc / jnp.maximum(l, 1e-30)
 
 
-def paged_call(kern, q, pool, tables, lengths, layer, *, depth, width, name,
-               interpret):
+def paged_call(kern, q, pool, tables, lengths, layer, *, per_block,
+               buffer_depth, width, name, interpret):
     """``kern`` over a grid of one program per sequence: the block
     tables, lengths and layer prefetched as scalars, ``q``'s row and the
-    (S, H, ``width``) output's blocked per sequence, the pool left in HBM
-    for the kernel's DMAs into a ring of ``depth`` page buffers. ``name``
-    names the call, so that a profile finds the kernel by it whatever
-    program calls it."""
+    (S, H, ``width``) float32 output blocked per sequence, the pool left
+    in HBM for the kernel's DMAs into a ring of ``buffer_depth`` blocks
+    of ``per_block`` pages (the depth clamped to [1, the table's
+    blocks]). ``name`` names the call, so that a profile finds the
+    kernel by it whatever program calls it."""
     S, H = q.shape[:2]
+    page = pool.shape[2:]
+    depth = max(1, min(buffer_depth, -(-tables.shape[1] // per_block)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S,),
@@ -114,42 +162,44 @@ def paged_call(kern, q, pool, tables, lengths, layer, *, depth, width, name,
                                lambda s, *_: (s, 0, 0)),
                   pl.BlockSpec(memory_space=pl.ANY)],   # pool stays HBM
         out_specs=pl.BlockSpec((None, H, width), lambda s, *_: (s, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((depth,) + pool.shape[2:], pool.dtype),
+        scratch_shapes=[pltpu.VMEM((depth, per_block) + page, pool.dtype),
                         pltpu.SemaphoreType.DMA((depth,))],
     )
     return pl.pallas_call(
         kern, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, H, width), jnp.float32),
         interpret=interpret, name=name,
     )(tables, lengths, layer.reshape(1), q, pool)
 
 
 def _decode_kernel(tables, lengths, layer, q_ref, pool, o_ref, buf, sem, *,
-                   page_size, depth, max_pages, n_kv, rep, sm_scale):
+                   n_kv, rep, sm_scale):
     H, hd = q_ref.shape
     qh = (q_ref[...].astype(jnp.float32) * sm_scale).reshape(n_kv, rep, hd)
 
-    def kv(page, half):
-        return page.reshape(page_size, n_kv, 2, hd)[:, :, half, :]
+    # the block goes float32 before K and V are split off its fused head
+    # axis: split as packed bf16 rows it cost more than bf16 score dots
+    # saved (7-10% a call on a TPU v5e at olmo-1b's and nemo's shapes)
+    def kv(block, half):
+        return block.astype(jnp.float32).reshape(
+            block.shape[0], n_kv, 2, hd)[:, :, half, :]
 
-    def scores(page):
-        k = kv(page, 0)
+    def scores(block):
+        k = kv(block, 0)
         return jnp.concatenate(
             [jax.lax.dot_general(qh[g], k[:, g], (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-             for g in range(n_kv)], axis=0)                   # (H, ps)
+             for g in range(n_kv)], axis=0)                   # (H, rows)
 
-    def values(p, page):
-        v, ph = kv(page, 1), p.reshape(n_kv, rep, page_size)
+    def values(p, block):
+        v, ph = kv(block, 1), p.reshape(n_kv, rep, block.shape[0])
         return jnp.concatenate(
             [jax.lax.dot_general(ph[g], v[:, g], (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
              for g in range(n_kv)], axis=0)                   # (H, hd)
 
-    o_ref[...] = walk_pages(
-        tables, lengths, layer, pool, buf, sem, page_size=page_size,
-        depth=depth, max_pages=max_pages, rows=H, width=hd, scores=scores,
-        values=values).astype(o_ref.dtype)
+    o_ref[...] = walk_pages(tables, lengths, layer, pool, buf, sem, rows=H,
+                            width=hd, scores=scores, values=values)
 
 
 def _stacked(pool, layer, rank=4):
@@ -170,23 +220,26 @@ def paged_attention_fwd(q, pool, tables, lengths, layer=0, *, buffer_depth=2,
     sequence's reserved pages); lengths: (S,) valid tokens per sequence;
     layer: the layer of ``pool`` to attend (int or traced int32 scalar;
     0 for a 4-D pool).  The kernel reads the pages of that layer where
-    they lie.  Returns (S, H, hd).  ``buffer_depth`` is the number of
-    page buffers kept in flight (static; clamped to [1, max_pages])."""
+    they lie.  Returns (S, H, hd) float32.  ``buffer_depth`` is the
+    number of pages kept in flight (static; ``paged_call``)."""
     interpret = resolve_interpret(interpret)
     pool, layer = _stacked(pool, layer)
     _, H, hd = q.shape
-    _, _, page_size, kv2, _ = pool.shape
+    kv2 = pool.shape[3]
     n_kv = kv2 // 2
     rep = H // n_kv
     assert n_kv * rep == H, (H, n_kv)
-    max_pages = tables.shape[1]
-    depth = max(1, min(buffer_depth, max_pages))
     sm_scale = sm_scale if sm_scale is not None else hd ** -0.5
-    kern = functools.partial(
-        _decode_kernel, page_size=page_size, depth=depth,
-        max_pages=max_pages, n_kv=n_kv, rep=rep, sm_scale=sm_scale)
-    return paged_call(kern, q, pool, tables, lengths, layer, depth=depth,
-                      width=hd, name="paged_attention", interpret=interpret)
+    kern = functools.partial(_decode_kernel, n_kv=n_kv, rep=rep,
+                             sm_scale=sm_scale)
+    # a page a block: this kernel is bound per row (K and V split off the
+    # fused head axis), not by its loop, and a block is contracted whole,
+    # so an idle slot would attend a block of masked rows. 8-page blocks
+    # cost mistral-nemo's decode cell 5.5% of its tokens/s and olmo-1b's
+    # chat step 27% on a TPU v5e
+    return paged_call(kern, q, pool, tables, lengths, layer, per_block=1,
+                      buffer_depth=buffer_depth, width=hd,
+                      name="paged_attention", interpret=interpret)
 
 
 def walk_pages_xla(pool, tables, lengths, layer, *, buffer_depth, scores,

@@ -15,9 +15,10 @@ of the key. One page of rows serves all heads at once.
 The page walk (grid, block tables, DMA ring, ragged mask, online
 softmax) is ``kernels/paged_attention``'s ``walk_pages``, with the score
 and value contractions of a latent row: one program per sequence walks
-its table with ``buffer_depth`` page copies in flight, straight out of
-the stacked pool at a traced ``layer`` index. The roofline share counts
-the row's 576 columns, so the padding shows as lost share.
+its table a block of pages (at least 128 rows) at a time, with
+``buffer_depth`` blocks in flight, straight out of the stacked pool at a
+traced ``layer`` index. The roofline share counts the row's 576
+columns, so the padding shows as lost share.
 
 ``paged_mla_attention_xla`` is the XLA twin, on ``walk_pages_xla``.
 """
@@ -28,28 +29,33 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.paged_attention import (_stacked, paged_call, walk_pages,
+from repro.kernels.paged_attention import (_stacked, pages_per_block,
+                                           paged_call, walk_pages,
                                            walk_pages_xla)
 from repro.kernels.quant import resolve_interpret
 
 
 def _mla_kernel(tables, lengths, layer, q_ref, pool, o_ref, buf, sem, *,
-                page_size, depth, max_pages, latent, sm_scale):
-    q = q_ref[...].astype(jnp.float32) * sm_scale              # (H, W)
+                latent, sm_scale):
+    # bf16 queries meet bf16 rows as stored (their products are exact in
+    # the float32 accumulator); a float32 side keeps the dot float32
+    dt = jnp.promote_types(q_ref.dtype, pool.dtype)
+    q = q_ref[...].astype(dt)                                  # (H, W)
 
-    def scores(rows):                                          # (ps, W)
-        return jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
+    def scores(rows):                                          # (n, W)
+        return jax.lax.dot_general(q, rows.astype(dt),
+                                   (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32
+                                   ) * sm_scale
 
     def values(p, rows):
-        return jax.lax.dot_general(p, rows[:, :latent],
+        return jax.lax.dot_general(p, rows[:, :latent].astype(jnp.float32),
                                    (((1,), (0,)), ((), ())),
                                    preferred_element_type=jnp.float32)
 
     o_ref[...] = walk_pages(
-        tables, lengths, layer, pool, buf, sem, page_size=page_size,
-        depth=depth, max_pages=max_pages, rows=q_ref.shape[0], width=latent,
-        scores=scores, values=values).astype(o_ref.dtype)
+        tables, lengths, layer, pool, buf, sem, rows=q_ref.shape[0],
+        width=latent, scores=scores, values=values)
 
 
 def paged_mla_attention_fwd(q, pool, tables, lengths, layer=0, *, latent,
@@ -59,16 +65,16 @@ def paged_mla_attention_fwd(q, pool, tables, lengths, layer=0, *, latent,
     page_size, W) for one; tables: (S, max_pages) int32 page ids;
     lengths: (S,) valid positions per sequence; layer: the layer of
     ``pool`` to attend; latent: the leading columns of a row that are its
-    value. Returns (S, H, latent)."""
+    value. Returns (S, H, latent) float32."""
     interpret = resolve_interpret(interpret)
     pool, layer = _stacked(pool, layer, rank=3)
     assert pool.shape[3] == q.shape[2], (pool.shape, q.shape)
-    max_pages = tables.shape[1]
-    depth = max(1, min(buffer_depth, max_pages))
-    kern = functools.partial(
-        _mla_kernel, page_size=pool.shape[2], depth=depth,
-        max_pages=max_pages, latent=latent, sm_scale=sm_scale)
-    return paged_call(kern, q, pool, tables, lengths, layer, depth=depth,
+    kern = functools.partial(_mla_kernel, latent=latent, sm_scale=sm_scale)
+    per_block = pages_per_block(pool.shape[2],
+                                pool.shape[3] * pool.dtype.itemsize,
+                                tables.shape[1])
+    return paged_call(kern, q, pool, tables, lengths, layer,
+                      per_block=per_block, buffer_depth=buffer_depth,
                       width=latent, name="paged_mla_attention",
                       interpret=interpret)
 

@@ -56,7 +56,7 @@ Mechanics (DESIGN.md section 11):
   become device arrays (one fixed-width row per slot, trash-padded), slot
   insertion scatters the prefill cache into the request's pages, and the
   decode step attends through the ragged paged-attention kernel with
-  ``page_buffer_depth`` page loads in flight.  The host loop, scheduler
+  ``page_buffer_depth`` blocks of page loads in flight.  The host loop, scheduler
   and allocator decisions are IDENTICAL to the dense engine — paged is
   purely a KV-residency change — so greedy token streams stay
   bit-identical to dense at f32 (the differential tier in

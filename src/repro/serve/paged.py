@@ -25,8 +25,8 @@ Three jit-able pieces (wired into cells by ``serve/step.py``):
   (``dynamic_update_slice`` at ``(g, table[idx // bs], idx % bs)`` of the
   stacked leaf), then attend over the block table via
   ``kernels/ops.paged_attention`` — the ragged paged-attention kernel (or
-  its XLA twin) walking layer ``g``'s pages with ``buffer_depth`` loads in
-  flight.  The pool rides the layer scan's *carry* (not its scanned
+  its XLA twin) walking layer ``g``'s pages with ``buffer_depth`` blocks
+  of pages in flight.  The pool rides the layer scan's *carry* (not its scanned
   inputs and outputs), and the kernel is handed the whole stacked leaf
   with the layer index: the writes alias the donated pool and the reads
   address it in place, so a step moves the bytes it attends and its 16

@@ -1,4 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -177,6 +179,11 @@ def _paged_case(seed, S, H, Kv, hd, page_size, max_pages, lengths):
     (4, 4, 2, 16, 8, 6, (1, 13, 40, 48)),     # ragged incl. page-aligned
     (3, 8, 8, 32, 4, 8, (32, 7, 19)),         # MHA (rep=1), odd tails
     (2, 2, 1, 64, 16, 2, (16, 31)),           # single kv head, wide hd
+    # 20 pages of 16 rows: lengths end inside, at and one past 128 rows
+    # (the latent kernel's block), and past them
+    (5, 4, 2, 16, 16, 20, (1, 127, 128, 129, 300)),
+    # rows of 8 KiB (8 KV heads of 128, float32)
+    (3, 8, 8, 128, 16, 6, (1, 16, 70)),
 ])
 def test_paged_attention_kernel_matches_ref(depth, S, H, Kv, hd, ps,
                                             max_pages, lengths):
@@ -196,29 +203,118 @@ def test_paged_attention_kernel_matches_ref(depth, S, H, Kv, hd, ps,
     assert jnp.max(jnp.abs(got_x - want)) < 2e-5
 
 
-def test_paged_attention_ignores_trash_and_pad_positions():
+@pytest.mark.parametrize("poison", [1e6, float("nan")])
+@pytest.mark.parametrize("H,Kv,hd,ps,max_pages,lengths", [
+    (4, 2, 16, 8, 4, (5, 17, 26)),
+    (4, 2, 16, 8, 40, (5, 170, 260)),       # long, ragged tables
+    # a tensor-parallel shard's rows: 2 KV heads of 128 (mistral-nemo's 8
+    # over 4 chips)
+    (8, 2, 128, 16, 6, (5, 17, 70)),
+], ids=["short", "long", "tp_shard"])
+def test_paged_attention_ignores_trash_and_pad_positions(
+        H, Kv, hd, ps, max_pages, lengths, poison):
     """Only the first ``length`` positions of a sequence's own pages may
     contribute: corrupting the trash page, the unowned pages and the
-    owned-but-past-length tail must not move the output."""
+    owned-but-past-length tail — with a large value or NaN, which a zero
+    probability does not cancel — must not move the output."""
+    import numpy as np
     from repro.kernels import paged_attention as pa
-    q, pool, tables, lens = _paged_case(23, 3, 4, 2, 16, 8, 4, (5, 17, 26))
+    q, pool, tables, lens = _paged_case(23, 3, H, Kv, hd, ps, max_pages,
+                                        lengths)
     base = pa.paged_attention_fwd(q, pool, tables, lens, buffer_depth=2,
                                   interpret=True)
     owned = set()
-    import numpy as np
     tbl = np.asarray(tables)
-    for s, n in enumerate((5, 17, 26)):
-        owned.update(tbl[s, :-(-n // 8)].tolist())
+    for s, n in enumerate(lengths):
+        owned.update(tbl[s, :-(-n // ps)].tolist())
     poisoned = np.array(pool)
     for p in range(poisoned.shape[0]):
         if p not in owned:
-            poisoned[p] = 1e6            # trash + unowned pages
-    for s, n in enumerate((5, 17, 26)):
-        last = tbl[s, (n - 1) // 8]
-        poisoned[last, n % 8 or 8:] = 1e6   # past-length tail of last page
+            poisoned[p] = poison         # trash + unowned pages
+    for s, n in enumerate(lengths):
+        last = tbl[s, (n - 1) // ps]
+        poisoned[last, n % ps or ps:] = poison   # past-length tail
     got = pa.paged_attention_fwd(q, jnp.asarray(poisoned), tables, lens,
                                  buffer_depth=2, interpret=True)
     assert jnp.max(jnp.abs(got - base)) == 0.0
+
+
+def test_paged_attention_bf16_operands_match_f32_oracle():
+    """bf16 queries against bf16 pages over long ragged tables: the
+    kernel takes each page to float32 before it splits K and V, and
+    returns float32, so it matches the float32 oracle on the same bf16
+    values."""
+    from repro.kernels import paged_attention as pa
+    lengths = (1, 127, 128, 129, 300)
+    q, pool, tables, lens = _paged_case(19, 5, 4, 2, 16, 16, 20, lengths)
+    q, pool = q.astype(jnp.bfloat16), pool.astype(jnp.bfloat16)
+    want = ref.paged_attention_ref(q.astype(jnp.float32),
+                                   pool.astype(jnp.float32), tables, lens)
+    got = pa.paged_attention_fwd(q, pool, tables, lens, buffer_depth=2,
+                                 interpret=True)
+    assert got.dtype == jnp.float32
+    assert jnp.max(jnp.abs(got - want)) < 2e-5
+
+
+@pytest.mark.parametrize("page_size,row,max_pages,pages", [
+    (16, (640,), 256, 8),       # Moonlight's latent pages: 128 rows
+    (8, (640,), 256, 16),
+    (16, (640,), 5, 5),         # a table shorter than a block
+    (16, (16, 128), 256, 8),    # mistral-nemo's K/V pages (8 KV heads)
+    (16, (32, 128), 128, 8),    # olmo-1b's (16 heads): 1 MiB
+    (16, (64, 128), 256, 4),    # wider rows: as many pages as VMEM holds
+    (16, (512, 128), 256, 1),   # a page past the budget: still one
+])
+def test_pages_per_block_from_the_page_shape(page_size, row, max_pages,
+                                             pages):
+    """The block rule, from a page's shape alone: the fewest whole pages
+    that give 128 rows, never more than the table holds nor than
+    ``MAX_BLOCK_BYTES`` of VMEM holds, and at least one."""
+    import math
+    from repro.kernels import paged_attention as pa
+    row_bytes = math.prod(row) * 2                       # bf16
+    n = pa.pages_per_block(page_size, row_bytes, max_pages)
+    assert n == pages
+    assert n <= max_pages
+    assert n == 1 or n * page_size * row_bytes <= pa.MAX_BLOCK_BYTES
+
+
+def _ring(fwd, *args):
+    """The VMEM ring of ``fwd``'s page walk, (depth, pages a block,
+    page_size, *row), read off the traced ``pallas_call``."""
+    (eqn,) = [e for e in jax.make_jaxpr(fwd)(*args).jaxpr.eqns
+              if e.primitive.name == "pallas_call"]
+    return [v.aval.shape for v in eqn.params["jaxpr"].invars
+            if str(v.aval.memory_space) == "vmem"][0]
+
+
+@pytest.mark.parametrize("kind,row,max_pages,pages", [
+    ("kv", (4, 128), 256, 1),    # a tensor-parallel shard's K/V rows
+    ("kv", (16, 128), 256, 1),   # mistral-nemo's K/V rows
+    ("kv", (32, 128), 128, 1),   # olmo-1b's
+    ("latent", (640,), 256, 8),  # Moonlight's latent rows: 128 a block
+    ("latent", (640,), 5, 5),
+])
+def test_paged_kernels_walk_blocks_of_their_own_size(kind, row, max_pages,
+                                                     pages):
+    """The K/V kernel walks a page a block whatever its rows' width (it is
+    bound per row, and a block is contracted whole); the latent kernel
+    walks the blocks ``pages_per_block`` gives its page shape."""
+    from repro.kernels import paged_attention as pa
+    from repro.kernels import paged_mla_attention as pm
+    S, ps = 2, 16
+    sds = jax.ShapeDtypeStruct
+    tables = sds((S, max_pages), jnp.int32)
+    lens = sds((S,), jnp.int32)
+    pool = sds((3, S * max_pages + 1, ps) + row, jnp.bfloat16)
+    if kind == "kv":
+        q = sds((S, row[0], row[1]), jnp.bfloat16)
+        fwd = functools.partial(pa.paged_attention_fwd, interpret=True)
+    else:
+        q = sds((S, 16, row[0]), jnp.bfloat16)
+        fwd = functools.partial(pm.paged_mla_attention_fwd, latent=512,
+                                sm_scale=0.1, interpret=True)
+    assert _ring(fwd, q, pool, tables, lens)[1:] == (pages, ps) + row
 
 
 def _stacked_case(seed, L, *args):
@@ -353,15 +449,21 @@ def _mla_oracle(q, pool, tables, lengths, latent, scale):
 
 @pytest.mark.parametrize("depth", [1, 2, 3])
 @pytest.mark.parametrize("layer", [0, 2])
-def test_paged_mla_attention_kernel_matches_twin_and_oracle(depth, layer):
+@pytest.mark.parametrize("ps,max_pages,lengths", [
+    (8, 6, (1, 13, 40, 48)),                    # one block: the table
+    (16, 20, (1, 127, 128, 129, 300)),          # three 128-row blocks
+], ids=["block", "blocks"])
+def test_paged_mla_attention_kernel_matches_twin_and_oracle(
+        ps, max_pages, lengths, layer, depth):
     """The latent kernel (interpret) and its XLA twin, handed a stacked
     pool and a traced layer index, match a full softmax over that layer's
     rows — scores against the whole row, output over its latent prefix —
-    on ragged lengths (a page-aligned one among them) with trash-padded
-    tables."""
+    on ragged lengths (page- and block-aligned ones among them, and ones
+    a row past a block) with trash-padded tables."""
     from repro.kernels import paged_mla_attention as pm
-    lengths = (1, 13, 40, 48)
-    q, pool, tables, lens = _mla_case(41, 3, 4, 4, 128, 40, 8, 6, lengths)
+    S = len(lengths)
+    q, pool, tables, lens = _mla_case(41, 3, S, 4, 128, 40, ps, max_pages,
+                                      lengths)
     scale = 24 ** -0.5
     want = _mla_oracle(q, pool[layer], tables, lens, 40, scale)
     got_k = jax.jit(lambda l: pm.paged_mla_attention_fwd(
@@ -370,30 +472,54 @@ def test_paged_mla_attention_kernel_matches_twin_and_oracle(depth, layer):
     got_x = jax.jit(lambda l: pm.paged_mla_attention_xla(
         q, pool, tables, lens, l, latent=40, sm_scale=scale,
         buffer_depth=depth))(layer)
-    assert got_k.shape == (4, 4, 40)
+    assert got_k.shape == (S, 4, 40)
     assert jnp.max(jnp.abs(got_k - want)) < 2e-5
     assert jnp.max(jnp.abs(got_x - got_k)) < 2e-5
 
 
-def test_paged_mla_attention_ignores_trash_and_pad_positions():
+def test_paged_mla_attention_bf16_scores_are_exact():
+    """bf16 queries against bf16 latent rows, over several blocks: scores
+    of the stored operands accumulated in float32 match the float32
+    oracle on the same bf16 values (the kernel returns float32)."""
+    from repro.kernels import paged_mla_attention as pm
+    lengths = (1, 127, 128, 129, 300)
+    q, pool, tables, lens = _mla_case(47, 2, 5, 4, 128, 40, 16, 20, lengths)
+    q, pool = q.astype(jnp.bfloat16), pool.astype(jnp.bfloat16)
+    want = _mla_oracle(q.astype(jnp.float32), pool[1].astype(jnp.float32),
+                       tables, lens, 40, 0.2)
+    got = pm.paged_mla_attention_fwd(q, pool, tables, lens, 1, latent=40,
+                                     sm_scale=0.2, buffer_depth=2,
+                                     interpret=True)
+    assert got.dtype == jnp.float32
+    assert jnp.max(jnp.abs(got - want)) < 2e-5
+
+
+@pytest.mark.parametrize("poison", [1e6, float("nan")])
+@pytest.mark.parametrize("ps,max_pages,lengths", [
+    (8, 4, (5, 17, 26)),                    # one block: the whole table
+    (8, 40, (5, 170, 260)),                 # blocks of 16 pages, ragged
+], ids=["block", "blocks"])
+def test_paged_mla_attention_ignores_trash_and_pad_positions(
+        ps, max_pages, lengths, poison):
     """The trash page, unowned pages, past-length tails and the other
-    layers poisoned: the kernel's output does not move."""
+    layer poisoned, with a large value or NaN: the kernel's output does
+    not move."""
     import numpy as np
     from repro.kernels import paged_mla_attention as pm
-    lengths = (5, 17, 26)
-    q, pool, tables, lens = _mla_case(43, 2, 3, 4, 128, 40, 8, 4, lengths)
+    q, pool, tables, lens = _mla_case(43, 2, 3, 4, 128, 40, ps, max_pages,
+                                      lengths)
     kw = dict(latent=40, sm_scale=0.2, buffer_depth=2, interpret=True)
     base = pm.paged_mla_attention_fwd(q, pool, tables, lens, 1, **kw)
     tbl = np.asarray(tables)
     owned = {int(p) for s, n in enumerate(lengths)
-             for p in tbl[s, :-(-n // 8)]}
+             for p in tbl[s, :-(-n // ps)]}
     poisoned = np.array(pool)
-    poisoned[0] = 1e6                                  # the other layer
+    poisoned[0] = poison                               # the other layer
     for p in range(poisoned.shape[1]):
         if p not in owned:
-            poisoned[1, p] = 1e6                       # trash + unowned
+            poisoned[1, p] = poison                    # trash + unowned
     for s, n in enumerate(lengths):
-        poisoned[1, tbl[s, (n - 1) // 8], n % 8 or 8:] = 1e6
+        poisoned[1, tbl[s, (n - 1) // ps], n % ps or ps:] = poison
     got = pm.paged_mla_attention_fwd(q, jnp.asarray(poisoned), tables, lens,
                                      1, **kw)
     assert jnp.max(jnp.abs(got - base)) == 0.0
