@@ -9,7 +9,8 @@ own functions (``repro.launch.serve``: ``parse_args`` -> ``load_model`` ->
 ``build_engine`` -> ``make_load`` -> ``serve``) at olmo-1b's published
 widths with random params from ``--seed``: 16 requests, prompts cycled over
 128 and 512 tokens, 64 new tokens each, through
-``ContinuousEngine(paged=True, n_slots=8, cache_len=1024, block_size=16)``.
+``ContinuousEngine(n_slots=8, cache_len=1024, block_size=16)``, which keeps
+olmo-1b's KV state in the page pool.
 Every check that fails ends the run with a nonzero exit.  When JAX finds
 no TPU it exits nonzero before any work and prints no result.  The last
 line of stdout is one JSON object:
@@ -39,7 +40,7 @@ from repro.launch import serve as serve_cli  # noqa: E402
 
 # The published-width run: two prompt lengths only, because prefill
 # compiles once per distinct prompt length.
-SERVE_FLAGS = ["--arch", "olmo-1b", "--paged", "--batch", "8",
+SERVE_FLAGS = ["--arch", "olmo-1b", "--batch", "8",
                "--cache-len", "1024", "--block-size", "16",
                "--requests", "16", "--prompt-lens", "128,512",
                "--max-new", "64"]
@@ -234,6 +235,7 @@ def one_chip(seed: int, flags=SERVE_FLAGS) -> None:
     stats = jax.devices()[0].memory_stats() or {}
     print(f"[smoke] peak_bytes_in_use {stats.get('peak_bytes_in_use')}")
     check_requests(cfg, probe, reqs, args.max_new)
+    check(eng.paged, "the engine keeps olmo-1b's KV state in the page pool")
     check(KERNEL_MARKER in eng.cells.decode_hlo_text(eng.params),
           f"decode program holds {KERNEL_MARKER} (the Pallas kernel)")
     check_kernel(cfg, probe, seed)

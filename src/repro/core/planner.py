@@ -76,7 +76,7 @@ class OffloadPlan:
 # compute — is the trustworthy measurement when present; the SLO sweep is
 # next (probe beside policy-controlled trace traffic — the admission
 # regime an offloaded deployment would actually run); then the paged
-# sweep (probe beside paged-pool decode traffic); the single-device dense
+# sweep (probe beside paged-pool decode traffic); the single-device load
 # sweep is the fallback.
 SERVE_SWEEP_EXPERIMENTS = ("serve.sharded_sweep", "serve.slo_sweep",
                            "serve.paged_attention", "serve.load_sweep")

@@ -326,8 +326,9 @@ def paged_sweep(duration: float = 0.3, arch: str = "olmo-1b",
     page-granular bytes touched per token vs the valid-token ideal, the
     wire-bytes idiom applied to KV reads (relative = utilization; the
     page-size knob trades this against table length).  The engine half
-    re-runs the offered-load sweep with ``paged=True`` so planner rule
-    5's ``load_*`` headroom rows exist beside *paged* decode traffic.
+    re-runs the offered-load sweep on the engine (paged for ``arch``'s
+    all-attention config) so planner rule 5's ``load_*`` headroom rows
+    exist beside *paged* decode traffic.
     """
     from repro.kernels import ops as kops
 
@@ -390,11 +391,10 @@ def paged_sweep(duration: float = 0.3, arch: str = "olmo-1b",
     # sweep re-run with the paged engine, feeding planner rule 5
     params = registry.init_params(cfg, jax.random.key(0))
     eng = ContinuousEngine(cfg, params, n_slots=n_slots,
-                           cache_len=cache_len, block_size=block_size,
-                           paged=True)
+                           cache_len=cache_len, block_size=block_size)
     eng_params = {"arch": cfg.name, "n_slots": n_slots,
                   "cache_len": cache_len, "block_size": block_size,
-                  "kv_blocks": eng.kv.n_blocks, "paged": True,
+                  "kv_blocks": eng.kv.n_blocks, "paged": eng.paged,
                   "page_buffer_depth": eng.cells.buffer_depth,
                   "prompt_lens": list(prompt_lens),
                   "max_new_tokens": max_new}
@@ -686,7 +686,7 @@ def timeline(duration: float = 0.3,
              prompt_lens: tuple = (8, 16), max_new: int = 8,
              max_requests: int = 16,
              fabric_condition: str = "clean", slo: bool = False,
-             paged: bool = False, tp_size: int = 1,
+             tp_size: int = 1,
              trace_out: Optional[str] = None,
              seed: int = 0) -> list[Record]:
     """Traced serve runs: span-time decomposition per load level.
@@ -707,8 +707,8 @@ def timeline(duration: float = 0.3,
     Composes the serving layers: ``fabric_condition`` injects degraded
     wire stalls (spans labeled by condition), ``slo`` arms SLO-driven
     admission off the run's own measured medians (shed/preempt instants
-    carry the projected TTFT that justified them), ``paged``/``tp_size``
-    swap the KV residency / shard the decode.
+    carry the projected TTFT that justified them), ``tp_size`` shards
+    the decode.
     """
     from repro.obs import trace as obs_trace
 
@@ -731,7 +731,7 @@ def timeline(duration: float = 0.3,
         tr = obs_trace.Tracer(metadata={"experiment": EXPERIMENT_TIMELINE})
     eng = ContinuousEngine(cfg, params, n_slots=n_slots,
                            cache_len=cache_len, block_size=block_size,
-                           fabric=fabric, tp_size=tp_size, paged=paged,
+                           fabric=fabric, tp_size=tp_size,
                            tracer=tr)
     base_params = {"arch": cfg.name, "n_slots": n_slots,
                    "cache_len": cache_len, "block_size": block_size,
@@ -739,7 +739,7 @@ def timeline(duration: float = 0.3,
                    "prompt_lens": list(prompt_lens),
                    "max_new_tokens": max_new,
                    "fabric_condition": fabric_condition,
-                   "slo": bool(slo), "paged": bool(paged),
+                   "slo": bool(slo), "paged": eng.paged,
                    "tp_size": eng.tp_size}
     records: list[Record] = []
 

@@ -157,7 +157,7 @@ def expert_gmm_xla(lhs, rhs, group_sizes, layer=None, *,
     own expert's product (rows past the groups read 0) — E times the
     kernel's operations, for backends that run the model at test sizes.
     Plain einsums, where ``lax.ragged_dot`` has no batching rule for a
-    decode step mapped over slots (the dense engine's)."""
+    decode step mapped over slots (the per-slot caches' decode)."""
     rhs, layer = _stacked(rhs, layer)
     E = rhs.shape[1]
     expert = jnp.searchsorted(jnp.cumsum(group_sizes), jnp.arange(

@@ -100,13 +100,11 @@ def use_paged_kernel() -> bool:
 
 
 def paged_attention(q, pool, tables, lengths, layer=0, *,
-                    buffer_depth=None):
+                    buffer_depth=2):
     """Policy-dispatched ragged paged-attention decode over ``pool``'s
     layer ``layer`` (see ``kernels/paged_attention.py`` for shapes: a
-    stacked 5-D pool or one 4-D layer).  ``buffer_depth=None`` reads the
-    ``paged_buffer_depth`` policy knob."""
-    if buffer_depth is None:
-        buffer_depth = int(runtime.policy()["paged_buffer_depth"])
+    stacked 5-D pool or one 4-D layer), ``buffer_depth`` blocks of page
+    loads in flight."""
     return _paged_attention(q, pool, tables, lengths, layer,
                             buffer_depth=buffer_depth,
                             use_kernel=use_paged_kernel(),
@@ -128,12 +126,10 @@ def _paged_mla_attention(q, pool, tables, lengths, layer, *, latent,
 
 
 def paged_mla_attention(q, pool, tables, lengths, layer=0, *, latent,
-                        sm_scale, buffer_depth=None):
+                        sm_scale, buffer_depth=2):
     """Policy-dispatched paged latent attention over ``pool``'s layer
     ``layer`` (``kernels/paged_mla_attention.py``): the Pallas kernel or
     its XLA twin, picked as ``paged_attention`` picks."""
-    if buffer_depth is None:
-        buffer_depth = int(runtime.policy()["paged_buffer_depth"])
     return _paged_mla_attention(q, pool, tables, lengths, layer,
                                 latent=latent, sm_scale=float(sm_scale),
                                 buffer_depth=buffer_depth,

@@ -27,11 +27,11 @@ devices (``--devices`` fabricates host devices for it, which is why jax
 is imported only after argument parsing — the XLA flag must be set
 before the backend initializes).
 
-``--paged`` switches the continuous engine's KV residency to the
-physical page pool (``serve/paged.py``): decode attends through the
-ragged paged-attention kernel with ``--buffer-depth`` page loads in
-flight.  Token streams are identical to the dense engine; the latency
-decomposition shows what the paging indirection costs (or saves).
+The continuous engine keeps the KV state of every config the page pool
+can hold in the physical page pool (``serve/paged.py``): decode attends
+through the ragged paged-attention kernel with ``--buffer-depth`` page
+loads in flight.  Windowed, recurrent and hybrid archs keep per-slot
+caches (``serve/step.make_cells`` decides).
 
 ``--trace FILE`` replays a recorded JSONL trace (arrivals, prompts,
 generation budgets, priority classes — ``serve/loadgen.py``) instead of
@@ -108,15 +108,10 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="tensor-parallel decode over this many devices "
                          "(continuous engine; params + per-slot KV "
                          "sequence sharded over a 'model' axis)")
-    ap.add_argument("--paged", action="store_true",
-                    help="physical paged-KV serving: one preallocated "
-                         "page pool per layer, per-request block tables, "
-                         "ragged paged-attention decode (continuous "
-                         "engine only; serve/paged.py)")
     ap.add_argument("--buffer-depth", type=int, default=2,
                     help="paged-attention page buffers in flight (DMA "
                          "double-buffering on TPU, page-gather width in "
-                         "the XLA twin); needs --paged")
+                         "the XLA twin)")
     ap.add_argument("--devices", type=int, default=0,
                     help="fabricate N host devices (XLA flag; must be set "
                          "before jax initializes, hence a CLI flag)")
@@ -176,18 +171,8 @@ def parse_args(argv=None) -> argparse.Namespace:
         ap.error(f"--tp-size {args.tp_size} exceeds the "
                  f"{len(jax.devices())} visible device(s) "
                  f"(fabricate more with --devices N)")
-    if args.static and args.paged:
-        ap.error("--paged swaps the continuous engine's KV residency; "
-                 "the static engine has no paged path (drop --static)")
     if args.buffer_depth < 1:
         ap.error("--buffer-depth must be >= 1")
-    if args.buffer_depth != 2 and not args.paged:
-        ap.error("--buffer-depth tunes the paged-attention walk; it "
-                 "needs --paged")
-    if args.paged and args.cache_len % args.block_size:
-        ap.error(f"--paged needs --cache-len divisible by --block-size "
-                 f"({args.cache_len} % {args.block_size} != 0): blocks "
-                 f"are physical pool pages")
     if args.static and (args.trace or args.slo):
         ap.error("--trace/--slo drive the continuous engine's arrival "
                  "pacing and admission policy; the static engine has "
@@ -220,7 +205,7 @@ def load_model(args):
 
 
 def build_engine(args, cfg, params):
-    """The continuous engine the flags describe (slots, KV residency,
+    """The continuous engine the flags describe (slots, page walk depth,
     tensor parallelism, fabric, SLO policy, tracer, log cap)."""
     from repro.fabric import ServeFabric, canonical_conditions
     from repro.serve.continuous import ContinuousEngine
@@ -236,7 +221,7 @@ def build_engine(args, cfg, params):
     return ContinuousEngine(cfg, params, n_slots=args.batch,
                             cache_len=args.cache_len,
                             block_size=args.block_size, fabric=fabric,
-                            tp_size=args.tp_size, paged=args.paged,
+                            tp_size=args.tp_size,
                             page_buffer_depth=args.buffer_depth,
                             slo=SLOPolicy.from_runtime() if args.slo
                             else None,
@@ -323,16 +308,16 @@ def serve(args, eng, reqs) -> float:
         print(f"[serve] trace: {args.trace_out} "
               f"({len(eng.tracer.events)} events; load in Perfetto or "
               f"chrome://tracing)")
-    _summary(args, reqs, elapsed)
+    _summary(args, reqs, elapsed, paged=eng.paged)
     return elapsed
 
 
-def _summary(args, reqs, elapsed: float) -> None:
+def _summary(args, reqs, elapsed: float, paged: bool = False) -> None:
     toks = sum(len(r.generated) for r in reqs)
     mode = "static" if args.static else (
         f"continuous tp={args.tp_size}" if args.tp_size > 1 else
         "continuous")
-    if args.paged:
+    if paged:
         mode += f" paged(depth={args.buffer_depth})"
     if args.slo:
         mode += " slo"

@@ -30,11 +30,6 @@ _DEFAULT = {
     #                             pure-XLA twin elsewhere; pallas forces
     #                             the kernel (interpreted on CPU — the
     #                             correctness-test path)
-    "paged_buffer_depth": 2,    # page buffers in flight in the paged-
-    #                             attention walk (DMA double-buffering on
-    #                             TPU, gather width in the XLA twin); the
-    #                             serve.paged_attention sweep pins each
-    #                             depth explicitly
     "pallas_interpret": None,   # None = auto: interpreted on CPU, compiled
     #                             on TPU/GPU (kernels.quant.resolve_interpret
     #                             keys on the backend); booleans force

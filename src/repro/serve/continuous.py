@@ -1,4 +1,4 @@
-"""Continuous-batching serve engine: slot admission + per-slot decode.
+"""Continuous-batching serve engine: slot admission + all-slot decode.
 
 The static ``engine.Engine`` runs one batch to completion; this engine
 keeps a fixed set of decode *slots* live and admits queued requests as
@@ -8,19 +8,27 @@ waiting for the current batch to drain.
 
 Mechanics (DESIGN.md section 11):
 
-* **Per-slot caches.**  Decode caches are stacked along a leading slot
-  axis over batch-1 caches, so every slot carries its *own* position
-  vector — the one thing the shared-batch decode step cannot express
-  (its ``index`` is a single scalar for the whole batch).  The decode
-  step is ``jax.vmap`` over slots with ``in_axes=(None, 0, 0, 0)``; a
-  greedy run over equal-length prompts is token-identical to the static
-  engine (regression-tested).
+* **KV residency follows the config.**  ``serve/step.make_cells`` puts
+  the KV state of every config the page pool can hold
+  (``paged.paged_supported``: all-attention, no window) in the physical
+  page pool of ``serve/paged.py``: the allocator's block tables become
+  device arrays (one fixed-width row per slot, trash-padded), insertion
+  scatters the prefill cache into the request's pages, and the decode
+  step attends through the ragged paged-attention kernel with
+  ``page_buffer_depth`` blocks of page loads in flight.  Windowed,
+  recurrent and hybrid states stay in per-slot caches stacked along a
+  leading slot axis, decoded by ``jax.vmap`` over slots.  Every slot
+  carries its *own* position — the one thing the shared-batch decode
+  step cannot express.  The engine drives both through one calling
+  convention (``ServeCells``) and never asks which it holds; greedy
+  streams match the static engine and the float32 references
+  (``tests/test_serve_paged.py``, ``tests/test_mla_moe.py``).
 * **Admission.**  ``SlotScheduler`` + ``KVBlockAllocator``: FIFO, a
   request is admitted only when a slot is free AND the shared block pool
   covers prompt + ``max_new_tokens`` (conservative reservation, no
   preemption).  Prefill runs batch-1 at the exact prompt length (no
   left-padding — pad tokens would attend), and its caches are written
-  into the slot with one ``dynamic_update_slice`` per cache leaf.
+  into the request's pages (or its slot).
 * **Latency decomposition.**  Every request's lifecycle stamps (queue
   wait / TTFT / per-token decode) are taken on the engine clock; the
   clock is injectable (``clock=...``) so tests drive arrivals on virtual
@@ -40,27 +48,17 @@ Mechanics (DESIGN.md section 11):
   paper's question transposed to serving.
 
 * **Tensor parallelism.**  ``tp_size=N`` (or an explicit ``mesh=``)
-  routes all three cells — batch-1 prefill, vmapped slot decode, slot
-  insertion — through the mesh-aware builders in ``serve/step.py``:
-  params sharded by the decode rules, the per-slot KV sequence split
-  over the 'model' axis, per-slot tokens/positions replicated scalars.
+  routes all three cells — batch-1 prefill, the decode step, insertion
+  — through the mesh-aware build in ``serve/step.py``: params sharded by
+  the decode rules, the page pool split over 'model' on its fused head
+  axis (per-slot caches: on the KV sequence), per-slot tokens/positions
+  and tables replicated.
   The scheduler, KV allocator and the whole host loop are untouched —
   they account in slots and logical token positions, blind to device
   count — and greedy token streams stay bit-identical to the
   single-device engine (the differential tier in
   ``tests/test_serve_sharded.py`` holds them equal and pins the decode
   step's per-kind collective counts).
-
-* **Paged KV (``paged=True``).**  The per-slot caches are replaced by the
-  physical page pool of ``serve/paged.py``: the allocator's block tables
-  become device arrays (one fixed-width row per slot, trash-padded), slot
-  insertion scatters the prefill cache into the request's pages, and the
-  decode step attends through the ragged paged-attention kernel with
-  ``page_buffer_depth`` blocks of page loads in flight.  The host loop, scheduler
-  and allocator decisions are IDENTICAL to the dense engine — paged is
-  purely a KV-residency change — so greedy token streams stay
-  bit-identical to dense at f32 (the differential tier in
-  ``tests/test_serve_paged.py`` holds them equal at tp=1/2/4).
 
 Inactive slots decode garbage (fixed shapes keep one compiled step); the
 results are masked on the host and every admission overwrites the whole
@@ -84,7 +82,7 @@ from repro.obs.trace import annotate
 from repro.parallel import compat
 from repro.serve.kv import KVBlockAllocator, blocks_for
 from repro.serve.scheduler import ServeRequest, SlotScheduler
-from repro.serve.step import make_continuous_cells, make_paged_cells
+from repro.serve.step import make_cells
 
 
 @dataclass(frozen=True)
@@ -123,7 +121,7 @@ class ContinuousEngine:
                  prefill_per_step: Optional[int] = None,
                  clock: Callable[[], float] = time.perf_counter,
                  fabric=None, mesh=None, tp_size: int = 1,
-                 paged: bool = False, page_buffer_depth: int = 2,
+                 page_buffer_depth: int = 2,
                  slo=None, tracer=None, log_cap: Optional[int] = None,
                  debug: bool = False):
         # fabric: an optional repro.fabric.ServeFabric — the degraded-wire
@@ -144,11 +142,12 @@ class ContinuousEngine:
         # None keeps exact FIFO.  Swappable between runs via
         # ``engine.scheduler.slo``.
         #
-        # paged / page_buffer_depth: physical paged-KV serving (module
-        # docstring).  debug=True re-checks the allocator invariants on
-        # every slot recycle (KVBlockAllocator.check) — cheap at serve
-        # scale, and it catches table corruption at the step that caused
-        # it rather than at teardown.
+        # page_buffer_depth: page loads in flight in the paged decode's
+        # page walk (module docstring).  debug=True re-checks the
+        # allocator invariants on every slot recycle
+        # (KVBlockAllocator.check) — cheap at serve scale, and it catches
+        # table corruption at the step that caused it rather than at
+        # teardown.
         #
         # tracer: repro.obs span tracing — None resolves via the
         # ``obs_trace`` runtime knob, then the thread-local current tracer
@@ -163,7 +162,6 @@ class ContinuousEngine:
         self.n_slots = n_slots
         self.cache_len = cache_len
         self.clock = clock
-        self.paged = bool(paged)
         self.debug = bool(debug)
         self.fabric = fabric if fabric is not None \
             and not fabric.is_clean else None
@@ -177,15 +175,11 @@ class ContinuousEngine:
             mesh = compat.make_mesh((1, tp_size), ("data", "model"))
         if kv_blocks is None:
             kv_blocks = n_slots * blocks_for(cache_len, block_size)
-        if self.paged:
-            # pool pages = allocatable blocks + the trash page the padded
-            # table rows point at (serve/kv.py)
-            self.cells = make_paged_cells(
-                cfg, n_slots, cache_len, block_size, kv_blocks + 1,
-                mesh=mesh, buffer_depth=page_buffer_depth)
-        else:
-            self.cells = make_continuous_cells(cfg, n_slots, cache_len,
-                                               mesh=mesh)
+        # pool pages = allocatable blocks + the trash page the padded table
+        # rows point at (serve/kv.py)
+        self.cells = make_cells(cfg, n_slots, cache_len, block_size,
+                                kv_blocks + 1, mesh=mesh,
+                                buffer_depth=page_buffer_depth)
         self.tp_size = self.cells.tp_size
         self.params = self.cells.put_params(params)
         # n_shards frames the allocator's placement() view only — every
@@ -212,16 +206,18 @@ class ContinuousEngine:
         self._prefill = self.cells.prefill
         self._decode = self.cells.decode
         self._insert = self.cells.insert
-        if self.paged:
-            self._pool = self.cells.init_pool()
-            self._tables_np = np.full(
-                (n_slots, self.cells.max_pages), self.kv.trash_page,
-                np.int32)
-            self._tables_dev = jnp.asarray(self._tables_np)
-        else:
-            self._caches = self.cells.init_slot_caches()
+        self._state = self.cells.init_state()
+        self._tables_np = np.full((n_slots, self.cells.max_pages),
+                                  self.kv.trash_page, np.int32)
+        self._tables_dev = jnp.asarray(self._tables_np)
         self._tok = np.zeros((n_slots,), np.int32)
         self._idx = np.zeros((n_slots,), np.int32)
+
+    @property
+    def paged(self) -> bool:
+        """Whether the cells keep the KV state in the page pool (for
+        traces and records; the loop never asks)."""
+        return self.cells.paged
 
     # -- submission --------------------------------------------------------
 
@@ -262,7 +258,7 @@ class ContinuousEngine:
         """Admit + prefill the scheduler's next pick, if admissible.
 
         An SLO admission may preempt active slots to make room: each
-        victim's slot is reset here (token/index zeroed; paged tables
+        victim's slot is reset here (token/index zeroed; its table row
         re-pointed at the trash page) BEFORE the new prefill lands — the
         victim's pages went back to the pool, and its old slot may stay
         free while the candidate lands elsewhere, so without the reset
@@ -310,20 +306,17 @@ class ContinuousEngine:
             with annotate("serve.first_token"):
                 first = int(jnp.argmax(logits[0, -1]))
             with annotate("serve.insert"):
-                if self.paged:
-                    # the request's pages, trash-padded to the fixed table
-                    # width; insertion scatters the whole prefill cache
-                    # into them
-                    row = np.asarray(
-                        self.kv.padded_table(req.rid, self.cells.max_pages),
-                        np.int32)
-                    self._pool = self._insert(self._pool, slot_caches,
-                                              jnp.asarray(row))
-                    self._tables_np[slot] = row
-                    self._tables_dev = jnp.asarray(self._tables_np)
-                else:
-                    self._caches = self._insert(self._caches, slot_caches,
-                                                jnp.int32(slot))
+                # the request's pages, trash-padded to the fixed table
+                # width; insertion writes the whole prefill cache into the
+                # state (its pages, or its slot)
+                row = np.asarray(
+                    self.kv.padded_table(req.rid, self.cells.max_pages),
+                    np.int32)
+                self._state = self._insert(
+                    self._state, slot_caches,
+                    *self.cells.insert_args(slot, row))
+                self._tables_np[slot] = row
+                self._tables_dev = jnp.asarray(self._tables_np)
         self._tok[slot] = first
         self._idx[slot] = len(req.prompt)
         req.generated.append(first)
@@ -368,20 +361,13 @@ class ContinuousEngine:
                          condition=self.fabric.condition.name)
                 tr.end("engine", t=self._T(t_start + stall_s),
                        stalled_s=stall_s)
-        load = None
         with annotate("serve.decode", step=step, active=len(active)):
-            if self.paged:
-                logits, self._pool, *moe_load = self._decode(
-                    self.params, jnp.asarray(self._tok)[:, None],
-                    jnp.asarray(self._idx), self._pool, self._tables_dev)
-                load = moe_load[0] if moe_load else None
-            else:
-                logits, self._caches = self._decode(
-                    self.params, jnp.asarray(self._tok)[:, None, None],
-                    jnp.asarray(self._idx), self._caches)
+            logits, self._state, *moe_load = self._decode(
+                self.params, jnp.asarray(self._tok)[:, None],
+                jnp.asarray(self._idx), self._state, self._tables_dev)
+            load = moe_load[0] if moe_load else None
         with annotate("serve.sample", step=step):
-            last = logits[:, 0] if self.paged else logits[:, 0, -1]
-            nxt = jnp.argmax(last, axis=-1)
+            nxt = jnp.argmax(logits[:, 0], axis=-1)
             if load is None:
                 nxt = np.asarray(nxt)                       # host sync
             else:
@@ -415,15 +401,14 @@ class ContinuousEngine:
 
     def _reset_slot(self, slot: int, t_rel: Optional[float] = None) -> None:
         # keep the garbage decode of a free slot inside the cache bounds;
-        # the next admission overwrites the whole slot cache anyway
+        # the next admission overwrites the whole slot cache anyway.  The
+        # freed pages are back in the pool — point the slot's table row at
+        # the trash page so its garbage decode can never write into a page
+        # the next reservation hands out
         self._tok[slot] = 0
         self._idx[slot] = 0
-        if self.paged:
-            # the freed pages are back in the pool — point the slot's
-            # table row at the trash page so its garbage decode can never
-            # write into a page the next reservation hands out
-            self._tables_np[slot] = self.kv.trash_page
-            self._tables_dev = jnp.asarray(self._tables_np)
+        self._tables_np[slot] = self.kv.trash_page
+        self._tables_dev = jnp.asarray(self._tables_np)
         if self._slot_open[slot] and t_rel is not None:
             # close the slot-track request span at the vacating event's
             # own time (complete / preempt / deadline abort)
@@ -433,12 +418,10 @@ class ContinuousEngine:
             self.kv.check()
 
     def _kv_bytes(self) -> dict:
-        """Bytes of the pages in use, by what they hold (paged only):
-        ``{"kv_bytes": ...}`` or ``{"latent_bytes": ...}``."""
-        if not self.paged:
-            return {}
-        from repro.serve.paged import pool_geometry
-        g = pool_geometry(self.cfg, self.kv.n_pages, self.kv.block_size)
+        """Bytes of the pages in use, by what they hold:
+        ``{"kv_bytes": ...}``, ``{"latent_bytes": ...}``, or
+        ``{"slot_bytes": ...}`` for per-slot caches."""
+        g = self.cells.geometry
         return {f"{g['state_kind']}_bytes": g["page_bytes"] * self.kv.n_used}
 
     # -- run loop ----------------------------------------------------------
@@ -486,11 +469,8 @@ class ContinuousEngine:
                        paged=self.paged, tp_size=self.tp_size,
                        condition=(self.fabric.condition.name
                                   if self.fabric is not None else "clean"))
-            if self.paged:
-                from repro.serve.paged import pool_geometry
-                tr.instant("kv", "pool_geometry", "kv", t=self._t0,
-                           **pool_geometry(self.cfg, self.kv.n_pages,
-                                           self.kv.block_size))
+            tr.instant("kv", "pool_geometry", "kv", t=self._t0,
+                       **self.cells.geometry)
         self._idle_open = False
         now = 0.0
         while n_seen < len(arrivals) or self.scheduler.has_work:

@@ -9,7 +9,7 @@ admission a memory decision, not just a slot decision — a free slot with
 an exhausted pool stays empty, which is exactly the HBM-pressure behavior
 the ``serve.load_sweep`` characterization wants observable.
 
-Blocks are *physical* in the paged engine (DESIGN.md section 14): block
+Blocks are *physical* in the page pool (DESIGN.md section 14): block
 id ``b`` names page ``b`` of the preallocated ``[n_pages, block_size,
 2*n_kv_heads, head_dim]`` pool tensor ``serve/paged.py`` materializes per
 attention layer (``[n_pages, block_size, row]`` latent rows for latent
@@ -20,8 +20,9 @@ page indirection the ragged paged-attention kernel walks.  One extra
 block tables are fixed-width, and rows are padded with the trash id so
 unreserved pages have somewhere harmless to point — it is never
 allocated, and reads from it are always masked by the per-sequence
-length.  The dense per-slot engine (``paged=False``) keeps using the same
-allocator as pure bookkeeping over its slot caches (DESIGN.md sec. 11).
+length.  Configs the pool cannot hold (windowed, recurrent, hybrid)
+keep per-slot caches, and the same allocator is pure bookkeeping over
+them (DESIGN.md sec. 11).
 
 The allocator is **device-count-blind**: every decision (``can_reserve``,
 ``reserve``, ``release``) is made in *logical token positions*, never in

@@ -1,13 +1,13 @@
 """Physical block-paged KV: pool tensors, page insertion, paged decode.
 
-The dense continuous engine stacks a full ``cache_len`` KV cache per
-slot; the paged engine replaces that with ONE preallocated pool tensor
-per layer of the scanned group, stacked over the group's layers — shape
-``(G, n_pages, block_size, 2*Kv, hd)`` (layer, then pages; the kernel
-reads it at a layer index) — with K/V *head-interleaved* on the fused
-head axis (``[k0, v0, k1, v1, ...]``): a page is the unit of both allocation
-(``serve/kv.py`` block ids ARE page ids) and data movement (one DMA per
-page moves keys and values together).  Requests own pages through the
+Per-slot caches would stack a full ``cache_len`` KV cache per slot; the
+page pool is ONE preallocated tensor per layer of the scanned group,
+stacked over the group's layers — shape ``(G, n_pages, block_size,
+2*Kv, hd)`` (layer, then pages; the kernel reads it at a layer index) —
+with K/V *head-interleaved* on the fused head axis (``[k0, v0, k1, v1,
+...]``): a page is the unit of both allocation (``serve/kv.py`` block
+ids ARE page ids) and data movement (one DMA per page moves keys and
+values together).  Requests own pages through the
 allocator's block tables; the device sees fixed-width table rows padded
 with the trash page (id ``n_blocks``), so the decode step's shapes never
 depend on how many pages a request holds.
@@ -31,9 +31,9 @@ Three jit-able pieces (wired into cells by ``serve/step.py``):
   with the layer index: the writes alias the donated pool and the reads
   address it in place, so a step moves the bytes it attends and its 16
   token rows, never a copy of the pool.  Non-attention sublayers (norms,
-  MLP/MoE, residuals, logits) reuse the exact ``models/transformer`` code,
-  which is what keeps paged token streams bit-identical to the dense
-  engine at f32 (differential-tested at tp=1/2/4).
+  MLP/MoE, residuals, logits) reuse the exact ``models/transformer`` code;
+  served tokens are held to the float32 references of ``perfbench/``
+  and stay bit-identical across tp=1/2/4 at f32 (differential-tested).
 
 The pool's leaves follow the state kind (``state_kind``): K/V pages as
 above, or, for latent attention (``kv_lora_rank > 0``), *latent pages*
@@ -53,10 +53,11 @@ the sampled tokens.  A slot whose table row starts at the trash page
 holds no request: its token is routed to no expert, so free slots cost
 the expert layer nothing and ``load`` counts live tokens only.
 
-Paged serving supports all-attention families with full (non-windowed)
-attention — the architectures where a physical page pool buys long
-context and oversubscription; SSM/hybrid/SWA states keep the dense path.
-Latent attention runs on one device (``tp_size`` 1).
+The pool holds all-attention families with full (non-windowed)
+attention (``paged_supported``) — the architectures where a physical
+page pool buys long context and oversubscription; ``serve/step.make_cells``
+serves every such config from the pool and keeps SSM/hybrid/SWA states
+in per-slot caches.  Latent attention runs on one device (``tp_size`` 1).
 """
 from __future__ import annotations
 
@@ -79,16 +80,12 @@ def paged_supported(cfg: ArchConfig) -> bool:
 
 def check_paged(cfg: ArchConfig, cache_len: int, block_size: int,
                 tp_size: int = 1) -> None:
+    """Refuse what the pool cannot serve for a pageable ``cfg``."""
     if cfg.mla and tp_size > 1:
         raise ValueError(
             f"paged latent attention runs on one device: {cfg.name} has "
             f"kv_lora_rank={cfg.kv_lora_rank}, asked for tp_size={tp_size} "
             f"(tensor parallelism for latent attention is not supported)")
-    if not paged_supported(cfg):
-        raise ValueError(
-            f"paged KV serving needs an all-attention, non-windowed arch; "
-            f"{cfg.name} (family={cfg.family}, "
-            f"sliding_window={cfg.sliding_window}) keeps the dense path")
     if cache_len % block_size:
         raise ValueError(
             f"paged KV needs cache_len divisible by block_size "
@@ -247,7 +244,7 @@ def _paged_attn_decode(cfg: ArchConfig, p: dict, x, pool_l, g, idx, tables,
     k = common.apply_rope(k, pos, cfg.rope_theta)
 
     # write each slot's new token into its current page of layer g — the
-    # paged form of the dense path's cache dynamic_update_slice (free
+    # paged form of attn_decode's cache dynamic_update_slice (free
     # slots write the trash page: their tables are all-trash, reads stay
     # length-masked)
     fused = fuse_kv(k[:, 0], v[:, 0]).astype(pool_l.dtype)   # (S, 2Kv, hd)

@@ -4,14 +4,15 @@ decode cells: the KV cache is sequence-split over 'model' (flash-decode
 style) for normal batched decode, and over every mesh axis for the
 batch=1 long_500k cell (see parallel/sharding.decode_rules).
 
-``make_continuous_cells`` packages the three cells the continuous-
-batching engine drives (batch-1 prefill, vmapped slot decode, slot
-insertion) as one :class:`ServeCells`, either single-device (the
-engine's original plain-jit cells) or tensor-parallel over a
-``("data", "model")`` mesh with explicit in/out shardings, so the
-compiled steps are reshard-free at the call boundary and a silent
-resharding shows up as a collective-count mismatch (guarded in
-``tests/test_serve_sharded.py``).
+``make_cells`` packages the three programs the continuous-batching
+engine drives (batch-1 prefill, the all-slot decode step, insertion of
+a prefill into the state) as one :class:`ServeCells`, and picks where
+the KV state lives: the page pool of ``serve/paged.py`` for every config
+it can hold, per-slot caches for the rest.  The cells are either
+single-device or tensor-parallel over a ``("data", "model")`` mesh with
+explicit in/out shardings, so the compiled steps are reshard-free at the
+call boundary and a silent resharding shows up as a collective-count
+mismatch (guarded in ``tests/test_serve_sharded.py``).
 """
 from __future__ import annotations
 
@@ -26,6 +27,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.models import registry
 from repro.parallel import compat, sharding
+from repro.serve.kv import blocks_for
 
 
 def _ctx_for(mesh, shape: ShapeConfig):
@@ -151,87 +153,20 @@ def _in_ctx(ctx: sharding.ShardingCtx, fn: Callable) -> Callable:
 
 @dataclass
 class ServeCells:
-    """The continuous engine's three compiled cells + their placements.
+    """The continuous engine's three compiled programs + their placements.
 
-    ``mesh=None`` is the single-device build: ``put_params`` /
-    ``init_slot_caches`` are identity/host placements and the cells are
-    the engine's original plain ``jax.jit`` closures.  With a mesh, the
-    cells carry explicit in/out shardings (params by the decode rules,
-    slot caches via ``slot_cache_shardings``, tokens/positions replicated
-    scalars) and the placement helpers ``device_put`` accordingly.
-    """
-    cfg: ArchConfig
-    n_slots: int
-    cache_len: int
-    prefill: Callable        # (params, tokens[1,S]) -> (logits, base caches)
-    decode: Callable         # (params, tok[slot,1,1], idx[slot], slot caches)
-    insert: Callable         # (slot caches, base caches, slot) -> slot caches
-    mesh: Optional[object] = None
-    ctx: Optional[sharding.ShardingCtx] = None
-    param_sharding: Optional[object] = None     # pytree of NamedSharding
-    slot_sharding: Optional[object] = None      # slot-stacked cache pytree
-    _decode_text: Optional[str] = field(default=None, repr=False)
-
-    @property
-    def tp_size(self) -> int:
-        return 1 if self.mesh is None else int(dict(self.mesh.shape)
-                                               .get("model", 1))
-
-    @property
-    def n_devices(self) -> int:
-        return 1 if self.mesh is None else self.mesh.size
-
-    def put_params(self, params):
-        if self.param_sharding is None:
-            return params
-        return jax.device_put(params, self.param_sharding)
-
-    def init_slot_caches(self):
-        base = registry.init_decode_caches(self.cfg, 1, self.cache_len)
-        stacked = jax.tree_util.tree_map(
-            lambda a: jnp.stack([a] * self.n_slots), base)
-        if self.slot_sharding is None:
-            return stacked
-        return jax.device_put(stacked, self.slot_sharding)
-
-    # -- HLO inspection (tests + the sharded-sweep experiment) -------------
-
-    def decode_hlo_text(self, params) -> str:
-        """Compiled HLO of the slot-decode cell (cached; abstract args, so
-        this never touches — or donates — live buffers)."""
-        if self._decode_text is None:
-            p = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
-            tok = jax.ShapeDtypeStruct((self.n_slots, 1, 1), jnp.int32)
-            idx = jax.ShapeDtypeStruct((self.n_slots,), jnp.int32)
-            caches = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                self.init_slot_caches())
-            self._decode_text = self.decode.lower(
-                p, tok, idx, caches).compile().as_text()
-        return self._decode_text
-
-    def decode_collective_counts(self, params) -> dict:
-        """Trip-count-weighted per-kind collective counts of the compiled
-        slot-decode step — the silent-resharding guard: an implicit
-        resharding XLA inserts at the call boundary changes these."""
-        from repro.analysis import hlo
-        ops = hlo.parse_collectives(self.decode_hlo_text(params),
-                                    self.n_devices)
-        return dict(hlo.collective_counts(ops))
-
-
-@dataclass
-class PagedServeCells:
-    """The paged engine's three compiled cells + their placements.
-
-    The dense :class:`ServeCells` stack per-slot caches; here the KV state
-    is ONE physical page pool per layer (``serve/paged.py``) and the slot
-    dimension lives in the block *tables* — decode takes every slot's
-    token/position plus the (n_slots, max_pages) table array and returns
-    updated pool state.  Sharded builds split the pool over 'model' on the
-    fused head axis and replicate tables/tokens, mirroring the dense
-    cells' reshard-free call boundary.
+    The KV state lives where ``make_cells`` put it (``paged``): in the
+    physical page pool of ``serve/paged.py``, one leaf per layer with the
+    slot dimension in the block *tables*, or in per-slot caches stacked
+    along a leading slot axis.  Either way the engine drives the same
+    calling convention: ``decode(params, tok (S, 1), idx (S,), state,
+    tables (S, max_pages))`` returns ``(logits (S, 1, V), state)``, plus
+    the experts' load with routed experts; ``insert(state, base caches,
+    *insert_args(slot, table_row))`` returns the state.  ``mesh=None`` is
+    the single-device build: the placements are identities.  With a mesh
+    the programs carry explicit in/out shardings (params by the decode
+    rules, the state split over 'model', tokens/positions/tables
+    replicated) and the placement helpers ``device_put`` accordingly.
     """
     cfg: ArchConfig
     n_slots: int
@@ -239,19 +174,23 @@ class PagedServeCells:
     block_size: int
     n_pages: int
     buffer_depth: int
-    prefill: Callable        # (params, tokens[1,S]) -> (logits, base caches)
-    decode: Callable         # (params, tok[S,1], idx[S], pool, tables[S,mp])
-    #                          -> (logits, pool[, expert load])
-    insert: Callable         # (pool, base caches, table_row[mp]) -> pool
+    paged: bool
+    geometry: dict           # the state's footprint (pool_geometry's keys)
+    init: Callable           # () -> state, unplaced
+    prefill: Callable        # (params, tokens[1,P]) -> (logits, base caches)
+    decode: Callable         # (params, tok[S,1], idx[S], state, tables[S,mp])
+    #                          -> (logits, state[, expert load])
+    insert: Callable         # (state, base caches, *insert_args) -> state
     mesh: Optional[object] = None
     ctx: Optional[sharding.ShardingCtx] = None
     param_sharding: Optional[object] = None     # pytree of NamedSharding
-    pool_sharding: Optional[object] = None      # pool pytree of NamedSharding
+    state_sharding: Optional[object] = None     # state pytree of NamedSharding
     _decode_text: Optional[str] = field(default=None, repr=False)
 
     @property
     def max_pages(self) -> int:
-        return self.cache_len // self.block_size
+        """Width of a device table row: the pages of a whole cache."""
+        return blocks_for(self.cache_len, self.block_size)
 
     @property
     def tp_size(self) -> int:
@@ -267,130 +206,125 @@ class PagedServeCells:
             return params
         return jax.device_put(params, self.param_sharding)
 
-    def init_pool(self):
-        from repro.serve import paged
-        pool = paged.init_kv_pool(self.cfg, self.n_pages, self.block_size)
-        if self.pool_sharding is None:
-            return pool
-        return jax.device_put(pool, self.pool_sharding)
+    def init_state(self):
+        state = self.init()
+        if self.state_sharding is None:
+            return state
+        return jax.device_put(state, self.state_sharding)
+
+    def insert_args(self, slot: int, table_row) -> tuple:
+        """What ``insert`` takes after the state and the prefill caches:
+        the request's page ids, or its slot."""
+        return (jnp.asarray(table_row),) if self.paged else (jnp.int32(slot),)
+
+    # -- HLO inspection (tests + the sharded-sweep experiment) -------------
 
     def decode_hlo_text(self, params) -> str:
-        """Compiled HLO of the paged-decode cell (abstract args; cached)."""
+        """Compiled HLO of the decode program (cached; abstract args, so
+        this never touches — or donates — live buffers)."""
         if self._decode_text is None:
             p = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params)
             tok = jax.ShapeDtypeStruct((self.n_slots, 1), jnp.int32)
             idx = jax.ShapeDtypeStruct((self.n_slots,), jnp.int32)
-            pool = jax.tree_util.tree_map(
-                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-                jax.eval_shape(self.init_pool))
+            state = jax.eval_shape(self.init)
             tbl = jax.ShapeDtypeStruct((self.n_slots, self.max_pages),
                                        jnp.int32)
             self._decode_text = self.decode.lower(
-                p, tok, idx, pool, tbl).compile().as_text()
+                p, tok, idx, state, tbl).compile().as_text()
         return self._decode_text
 
     def decode_collective_counts(self, params) -> dict:
+        """Trip-count-weighted per-kind collective counts of the compiled
+        decode step — the silent-resharding guard: an implicit resharding
+        XLA inserts at the call boundary changes these."""
         from repro.analysis import hlo
         ops = hlo.parse_collectives(self.decode_hlo_text(params),
                                     self.n_devices)
         return dict(hlo.collective_counts(ops))
 
 
-def make_paged_cells(cfg: ArchConfig, n_slots: int, cache_len: int,
-                     block_size: int, n_pages: int, mesh=None,
-                     buffer_depth: int = 2) -> PagedServeCells:
-    """Build the paged engine's cells, single-device or sharded.
-
-    ``n_pages`` counts *physical* pages (the allocator's blocks plus its
-    trash page); ``buffer_depth`` is baked into the decode cell as the
-    static pipelining knob of the paged-attention walk.
-    """
-    from repro.serve import paged
-
-    paged.check_paged(cfg, cache_len, block_size,
-                      tp_size=1 if mesh is None
-                      else int(dict(mesh.shape).get("model", 1)))
-
-    def serve_prefill(params, tokens):
-        return registry.prefill(cfg, params, {"tokens": tokens},
-                                cache_len=cache_len)
-
-    def serve_decode(params, tokens, index, pool, tables):
-        return paged.paged_decode_step(cfg, params, tokens, index, pool,
-                                       tables, buffer_depth=buffer_depth)
-
-    def serve_insert(pool, base_caches, table_row):
-        return paged.insert_pages(cfg, pool, base_caches, table_row)
-
-    if mesh is None:
-        return PagedServeCells(
-            cfg=cfg, n_slots=n_slots, cache_len=cache_len,
-            block_size=block_size, n_pages=n_pages,
-            buffer_depth=buffer_depth,
-            prefill=jax.jit(serve_prefill),
-            decode=jax.jit(serve_decode, donate_argnums=3),
-            insert=jax.jit(serve_insert, donate_argnums=0))
-
-    ctx = sharding.ShardingCtx(
-        mesh, sharding.decode_rules("pod" in mesh.axis_names, False))
-    pspec = sharding.param_shardings(registry.abstract_params(cfg), ctx)
-    pool_shape = jax.eval_shape(
-        lambda: paged.init_kv_pool(cfg, n_pages, block_size))
-    poolspec = jax.tree_util.tree_map(
-        lambda a: compat.named_sharding(mesh, sharding.safe_spec(
-            a.shape, (None,) * (len(a.shape) - 2) + ("heads", None), ctx)),
-        pool_shape)
-    base_shape = registry.abstract_decode_caches(cfg, 1, cache_len)
-    bspec = cache_shardings(base_shape, ctx)
-    rep = compat.named_sharding(mesh, P())
-
-    return PagedServeCells(
-        cfg=cfg, n_slots=n_slots, cache_len=cache_len,
-        block_size=block_size, n_pages=n_pages, buffer_depth=buffer_depth,
-        prefill=jax.jit(_in_ctx(ctx, serve_prefill),
-                        in_shardings=(pspec, rep),
-                        out_shardings=(rep, bspec)),
-        decode=jax.jit(_in_ctx(ctx, serve_decode),
-                       in_shardings=(pspec, rep, rep, poolspec, rep),
-                       out_shardings=(rep, poolspec), donate_argnums=3),
-        insert=jax.jit(_in_ctx(ctx, serve_insert),
-                       in_shardings=(poolspec, bspec, rep),
-                       out_shardings=poolspec, donate_argnums=0),
-        mesh=mesh, ctx=ctx, param_sharding=pspec, pool_sharding=poolspec)
-
-
-def make_continuous_cells(cfg: ArchConfig, n_slots: int, cache_len: int,
-                          mesh=None) -> ServeCells:
+def make_cells(cfg: ArchConfig, n_slots: int, cache_len: int,
+               block_size: int, n_pages: int, mesh=None,
+               buffer_depth: int = 2) -> ServeCells:
     """Build the continuous engine's cells, single-device or sharded.
 
-    The sharded build uses the *batched* decode rules
-    (``decode_rules(long_context=False)`` — heads/mlp/vocab and the KV
-    sequence over 'model'), never the batch=1 long-context cell that
-    ``_ctx_for`` would pick: the engine's slot axis is the batch.
+    The one place that picks where the KV state lives: the page pool of
+    ``serve/paged.py`` for every config it can hold
+    (``paged.paged_supported``), per-slot caches for the rest (windowed
+    attention, recurrent and hybrid states).  A pageable config is held
+    to the pool's terms (``paged.check_paged``: pages tile ``cache_len``,
+    latent attention on one device); it never falls back.
+
+    ``n_pages`` counts *physical* pages (the allocator's blocks plus its
+    trash page); ``buffer_depth`` is baked into the paged decode program
+    as the static pipelining knob of the page walk.  The sharded build
+    uses the *batched* decode rules (``decode_rules(long_context=False)``:
+    heads/mlp/vocab, and the per-slot KV sequence, over 'model'), never
+    the batch=1 long-context cell ``_ctx_for`` would pick: the engine's
+    slot axis is the batch.
     """
+    from repro.serve import paged as paged_kv
+
+    is_paged = paged_kv.paged_supported(cfg)
+
     def serve_prefill(params, tokens):
         return registry.prefill(cfg, params, {"tokens": tokens},
                                 cache_len=cache_len)
 
-    def _slot_decode(params, tokens, index, caches):
-        return registry.decode_step(
-            cfg, params, {"tokens": tokens, "index": index}, caches)
+    if is_paged:
+        paged_kv.check_paged(cfg, cache_len, block_size,
+                             tp_size=1 if mesh is None
+                             else int(dict(mesh.shape).get("model", 1)))
+        geometry = paged_kv.pool_geometry(cfg, n_pages, block_size)
 
-    def serve_decode(params, tokens, index, caches):
-        return jax.vmap(_slot_decode, in_axes=(None, 0, 0, 0))(
-            params, tokens, index, caches)
+        def init():
+            return paged_kv.init_kv_pool(cfg, n_pages, block_size)
 
-    def serve_insert(caches, slot_caches, slot):
-        return jax.tree_util.tree_map(
-            lambda c, p: jax.lax.dynamic_update_slice_in_dim(
-                c, p[None].astype(c.dtype), slot, axis=0),
-            caches, slot_caches)
+        def serve_decode(params, tokens, index, pool, tables):
+            return paged_kv.paged_decode_step(cfg, params, tokens, index,
+                                              pool, tables,
+                                              buffer_depth=buffer_depth)
 
+        def serve_insert(pool, base_caches, table_row):
+            return paged_kv.insert_pages(cfg, pool, base_caches, table_row)
+    else:
+        def init():
+            base = registry.init_decode_caches(cfg, 1, cache_len)
+            return jax.tree_util.tree_map(
+                lambda a: jnp.stack([a] * n_slots), base)
+
+        # a "page" here is one allocator block's share of a slot's cache
+        state_bytes = sum(a.size * a.dtype.itemsize for a in
+                          jax.tree_util.tree_leaves(jax.eval_shape(init)))
+        geometry = {"n_pages": n_pages, "block_size": block_size,
+                    "page_bytes": state_bytes // (
+                        n_slots * blocks_for(cache_len, block_size)),
+                    "pool_bytes": state_bytes, "state_kind": "slot"}
+
+        def _slot_decode(params, tokens, index, caches):
+            return registry.decode_step(
+                cfg, params, {"tokens": tokens, "index": index}, caches)
+
+        def serve_decode(params, tokens, index, caches, tables):
+            # tables: the paged calling convention's, unread here
+            logits, caches = jax.vmap(_slot_decode, in_axes=(None, 0, 0, 0))(
+                params, tokens[:, :, None], index, caches)
+            return logits[:, 0], caches
+
+        def serve_insert(caches, base_caches, slot):
+            return jax.tree_util.tree_map(
+                lambda c, p: jax.lax.dynamic_update_slice_in_dim(
+                    c, p[None].astype(c.dtype), slot, axis=0),
+                caches, base_caches)
+
+    shared = dict(cfg=cfg, n_slots=n_slots, cache_len=cache_len,
+                  block_size=block_size, n_pages=n_pages,
+                  buffer_depth=buffer_depth, paged=is_paged,
+                  geometry=geometry, init=init)
     if mesh is None:
         return ServeCells(
-            cfg=cfg, n_slots=n_slots, cache_len=cache_len,
-            prefill=jax.jit(serve_prefill),
+            **shared, prefill=jax.jit(serve_prefill),
             decode=jax.jit(serve_decode, donate_argnums=3),
             insert=jax.jit(serve_insert, donate_argnums=0))
 
@@ -399,21 +333,26 @@ def make_continuous_cells(cfg: ArchConfig, n_slots: int, cache_len: int,
     pspec = sharding.param_shardings(registry.abstract_params(cfg), ctx)
     base_shape = registry.abstract_decode_caches(cfg, 1, cache_len)
     bspec = cache_shardings(base_shape, ctx)
-    slot_shape = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct((n_slots,) + a.shape, a.dtype),
-        base_shape)
-    sspec = slot_cache_shardings(slot_shape, ctx)
+    state_shape = jax.eval_shape(init)
+    if is_paged:
+        sspec = jax.tree_util.tree_map(
+            lambda a: compat.named_sharding(mesh, sharding.safe_spec(
+                a.shape, (None,) * (len(a.shape) - 2) + ("heads", None),
+                ctx)),
+            state_shape)
+    else:
+        sspec = slot_cache_shardings(state_shape, ctx)
     rep = compat.named_sharding(mesh, P())
 
     return ServeCells(
-        cfg=cfg, n_slots=n_slots, cache_len=cache_len,
+        **shared,
         prefill=jax.jit(_in_ctx(ctx, serve_prefill),
                         in_shardings=(pspec, rep),
                         out_shardings=(rep, bspec)),
         decode=jax.jit(_in_ctx(ctx, serve_decode),
-                       in_shardings=(pspec, rep, rep, sspec),
+                       in_shardings=(pspec, rep, rep, sspec, rep),
                        out_shardings=(rep, sspec), donate_argnums=3),
         insert=jax.jit(_in_ctx(ctx, serve_insert),
                        in_shardings=(sspec, bspec, rep),
                        out_shardings=sspec, donate_argnums=0),
-        mesh=mesh, ctx=ctx, param_sharding=pspec, slot_sharding=sspec)
+        mesh=mesh, ctx=ctx, param_sharding=pspec, state_sharding=sspec)

@@ -373,8 +373,8 @@ def test_paged_attention_stacked_ignores_other_layers(layer):
 def test_paged_attention_policy_dispatch(monkeypatch):
     """``ops.paged_attention`` routes per policy without a stale jit
     cache: ``pallas`` forces the kernel, ``xla`` the twin, ``auto`` keys
-    on the backend (the twin on this CPU container), and the
-    ``paged_buffer_depth`` knob reaches the dispatch as a static."""
+    on the backend (the twin on a CPU), and ``buffer_depth`` reaches the
+    dispatch as a static."""
     from repro import runtime
     from repro.kernels import paged_attention as pa_mod
 
@@ -392,9 +392,8 @@ def test_paged_attention_policy_dispatch(monkeypatch):
         assert ops.use_paged_kernel()
         jax.eval_shape(lambda: ops.paged_attention(q, pool, tables, lens))
         jax.eval_shape(lambda: ops.paged_attention(q, pool, tables, lens))
-        with runtime.use_policy(paged_buffer_depth=3):
-            jax.eval_shape(lambda: ops.paged_attention(q, pool, tables,
-                                                       lens))
+        jax.eval_shape(lambda: ops.paged_attention(q, pool, tables, lens,
+                                                   buffer_depth=3))
     assert seen == [2, 3], seen                # depth flip retraced; the
     #                                            repeat call was a cache hit
     got = ops.paged_attention(q, pool, tables, lens)   # auto path runs

@@ -20,7 +20,7 @@ from repro.models import mla, moe, registry
 from repro.serve import paged
 from repro.serve.continuous import ContinuousEngine
 from repro.serve.scheduler import ServeRequest
-from repro.serve.step import make_paged_cells
+from repro.serve.step import make_cells
 
 # f32 program vs f32 reference: summation order only (~1e-5 observed);
 # bf16 matmuls put the logits ~1e-2 off
@@ -33,14 +33,16 @@ def _cfg(dtype="float32"):
 
 
 def _served_logits(cfg, params, seqs, prompt_lens, cache_len=32, bs=8):
-    """Each sequence prefilled (its prompt) into its own slot of the paged
-    cells, then decoded teacher-forced over its remaining tokens, all
-    slots in one batch: the logits of every position from the last prompt
-    token on, per sequence."""
+    """Each sequence prefilled (its prompt) into its own slot of the
+    engine's cells (paged: the config is all attention), then decoded
+    teacher-forced over its remaining tokens, all slots in one batch: the
+    logits of every position from the last prompt token on, per
+    sequence."""
     n = len(seqs)
     max_pages = cache_len // bs
-    cells = make_paged_cells(cfg, n, cache_len, bs, n * max_pages + 1)
-    pool = cells.init_pool()
+    cells = make_cells(cfg, n, cache_len, bs, n * max_pages + 1)
+    assert cells.paged
+    pool = cells.init_state()
     tables = np.arange(n * max_pages, dtype=np.int32).reshape(n, max_pages)
     out = [[] for _ in seqs]
     for s, (seq, P) in enumerate(zip(seqs, prompt_lens)):
@@ -179,7 +181,8 @@ def test_paged_engine_serves_reference_tokens():
     key = jax.random.key(13)
     params = registry.init_params(cfg, key)
     eng = ContinuousEngine(cfg, params, n_slots=3, cache_len=48,
-                           block_size=8, paged=True, debug=True)
+                           block_size=8, debug=True)
+    assert eng.paged
     reqs = [ServeRequest(prompt=s, max_new_tokens=n) for s, n in
             zip(_seqs(cfg, (5, 12, 9, 3), seed=6), (7, 4, 9, 5))]
     eng.run(reqs)
@@ -210,20 +213,22 @@ def test_latent_attention_refuses_tensor_parallelism():
 
 
 def test_dense_engine_serves_the_paged_engines_tokens():
-    """The dense continuous engine (row caches per slot, its decode step
-    mapped over slots) and the paged one serve the same tokens."""
+    """The engine, built with no residency asked for, keeps the latent
+    rows in pages and serves the reference's best token at every
+    position of equal-budget requests."""
     cfg = _cfg()
-    params = registry.init_params(cfg, jax.random.key(14))
-
-    def served(**kw):
-        eng = ContinuousEngine(cfg, params, n_slots=3, cache_len=48,
-                               block_size=8, **kw)
-        reqs = [ServeRequest(prompt=s, max_new_tokens=6)
-                for s in _seqs(cfg, (5, 12, 9, 3), seed=15)]
-        eng.run(reqs)
-        return [list(r.generated) for r in reqs]
-
-    assert served() == served(paged=True)
+    key = jax.random.key(14)
+    params = registry.init_params(cfg, key)
+    eng = ContinuousEngine(cfg, params, n_slots=3, cache_len=48,
+                           block_size=8)
+    assert eng.paged and eng.cells.geometry["state_kind"] == "latent"
+    reqs = [ServeRequest(prompt=s, max_new_tokens=6)
+            for s in _seqs(cfg, (5, 12, 9, 3), seed=15)]
+    eng.run(reqs)
+    assert all(len(r.generated) == 6 for r in reqs)
+    out = ref.gaps(dataclasses.asdict(cfg), key,
+                   [(r.prompt, r.generated) for r in reqs])
+    assert max(float(g.max()) for g, _ in out) < LOGIT_TOL
 
 
 def test_grouped_moe_layers_count_experts_per_layer():

@@ -502,7 +502,8 @@ def test_engine_spans_reach_the_profiler(cfg_params):
             ServeRequest(prompt=base[:5], max_new_tokens=3,
                          arrival_s=40.0)]
     eng = ContinuousEngine(c, params, n_slots=2, cache_len=32,
-                           block_size=4, clock=_vclock(), paged=True)
+                           block_size=4, clock=_vclock())
+    assert eng.paged
     eng.run([ServeRequest(prompt=base, max_new_tokens=2)])    # compiles
     evs = _profiled(lambda: eng.run(reqs))
     names = {e[0] for e in evs}
@@ -581,13 +582,12 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import re
 import jax
 import jax.numpy as jnp
+import numpy as np
 from repro.configs import all_archs, smoke
 from repro.models import registry
 from repro.parallel import compat
-from repro.serve.step import make_continuous_cells, make_paged_cells
+from repro.serve.step import make_cells
 
-cfg = smoke(all_archs()["olmo-1b"])
-params = jax.eval_shape(lambda: registry.init_params(cfg, jax.random.key(0)))
 S, L, BS = 2, 32, 4
 i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
 mesh = compat.make_mesh((1, 2), ("data", "model"))
@@ -595,32 +595,32 @@ mesh = compat.make_mesh((1, 2), ("data", "model"))
 def module(fn, *args):
     return re.search(r"module @(\w+)", fn.lower(*args).as_text()).group(1)
 
-for m in (None, mesh):
-    for paged in (False, True):
-        if paged:
-            cells = make_paged_cells(cfg, S, L, BS, S * L // BS + 1, mesh=m)
-            state = jax.eval_shape(cells.init_pool)
-            dec = (params, i32(S, 1), i32(S), state, i32(S, L // BS))
-            slot = i32(L // BS)
-        else:
-            cells = make_continuous_cells(cfg, S, L, mesh=m)
-            state = jax.eval_shape(cells.init_slot_caches)
-            dec = (params, i32(S, 1, 1), i32(S), state)
-            slot = i32()
+# a config the page pool holds, and a windowed one it cannot
+for arch, paged in (("olmo-1b", True), ("h2o-danube-3-4b", False)):
+    cfg = smoke(all_archs()[arch])
+    params = jax.eval_shape(
+        lambda: registry.init_params(cfg, jax.random.key(0)))
+    for m in (None, mesh):
+        cells = make_cells(cfg, S, L, BS, S * L // BS + 1, mesh=m)
+        assert cells.paged == paged, arch
+        state = jax.eval_shape(cells.init_state)
+        dec = (params, i32(S, 1), i32(S), state, i32(S, cells.max_pages))
+        ins = jax.eval_shape(lambda: cells.insert_args(
+            0, np.zeros(cells.max_pages, np.int32)))
         tokens = i32(1, 8)
         _, base = jax.eval_shape(cells.prefill, params, tokens)
         got = (module(cells.prefill, params, tokens),
                module(cells.decode, *dec),
-               module(cells.insert, state, base, slot))
+               module(cells.insert, state, base, *ins))
         assert got == ("jit_serve_prefill", "jit_serve_decode",
-                       "jit_serve_insert"), (m is not None, paged, got)
+                       "jit_serve_insert"), (arch, m is not None, got)
 print("ok")
 """
 
 
 def test_step_programs_have_stable_names():
-    """Every build of the engine's cells (dense and paged, one device and
-    a 2-device mesh) lowers to modules ``jit_serve_prefill``,
+    """Every build of the engine's cells (page pool and per-slot caches,
+    one device and a 2-device mesh) lowers to modules ``jit_serve_prefill``,
     ``jit_serve_decode`` and ``jit_serve_insert``: the names a profile's
     readers key on."""
     import os

@@ -2,12 +2,13 @@
 forced host devices (subprocess, like test_overlap).
 
 The tensor-parallel engine (``ContinuousEngine(tp_size=N)`` routing its
-cells through ``serve/step.make_continuous_cells``) may only change
+cells through ``serve/step.make_cells``) may only change
 *placement*: on the same seeded request set its emitted token streams
 must be bit-identical to the single-device engine — for a burst and for
 mixed arrivals on a virtual clock — its scheduling decisions
-(``admit_log``) identical, and the compiled slot-decode step must issue
-exactly the expected per-kind collectives (the silent-resharding guard:
+(``admit_log``) identical, and the compiled decode step must issue
+exactly the expected per-kind collectives, for the page pool and for
+per-slot caches (the silent-resharding guard:
 a resharding XLA sneaks into the hot loop changes the counts before it
 changes any latency number).  A ``ServeFabric`` straggler must compose
 with the sharded engine: host-side stalls drag the whole TP step,
@@ -91,16 +92,29 @@ def mixed(tp):
 assert mixed(4) == mixed(1)
 
 # (c) the silent-resharding guard: per-kind trip-count-weighted
-# collective counts of the compiled slot-decode cell match an explicit
+# collective counts of the compiled decode step match an explicit
 # expectation, identically at tp=2 and tp=4 (the schedule is a function
 # of the sharding rules, not the axis size), and the single-device build
-# has no collectives at all
-EXPECT = {"all-reduce": 1.0, "all-gather": 2.0}
-counts = {tp: engines[tp].cells.decode_collective_counts(engines[tp].params)
-          for tp in (1, 2, 4)}
-assert counts[1] == {}, counts[1]
-assert counts[2] == EXPECT, counts[2]
-assert counts[4] == EXPECT, counts[4]
+# has no collectives at all — for the page pool (the olmo config) and
+# for per-slot caches (the windowed h2o-danube config, which the pool
+# cannot hold)
+def guard(engines, expect):
+    counts = {tp: e.cells.decode_collective_counts(e.params)
+              for tp, e in engines.items()}
+    assert counts[1] == {}, counts[1]
+    assert counts[2] == expect, counts[2]
+    assert counts[4] == expect, counts[4]
+
+assert all(e.paged for e in engines.values())
+guard(engines, {"all-reduce": 1.0, "all-gather": 1.0})
+swa = dataclasses.replace(smoke(all_archs()["h2o-danube-3-4b"]),
+                          dtype="float32")
+swa_params = registry.init_params(swa, jax.random.key(0))
+slot_engines = {tp: ContinuousEngine(swa, swa_params, n_slots=N_SLOTS,
+                                     cache_len=CACHE_LEN, block_size=BS,
+                                     tp_size=tp) for tp in (1, 2, 4)}
+assert not any(e.paged for e in slot_engines.values())
+guard(slot_engines, {"all-reduce": 1.0, "all-gather": 2.0})
 
 # (d) ServeFabric straggler composes with the sharded engine: the stalls
 # are host-side, so one slow device drags the whole tensor-parallel

@@ -187,7 +187,7 @@ def test_paged_decode_cell_compiles_with_kernel(one_chip, arch, layers,
     left."""
     from repro.models import registry
     from repro.serve import paged
-    from repro.serve.step import make_paged_cells
+    from repro.serve.step import make_cells
 
     cfg = all_archs()[arch]
     if layers is not None:
@@ -202,12 +202,12 @@ def test_paged_decode_cell_compiles_with_kernel(one_chip, arch, layers,
 
     with runtime.use_policy(paged_attention_impl="pallas",
                             pallas_interpret=False):
-        cells = make_paged_cells(cfg, n_slots, cache_len, block, n_pages)
+        cells = make_cells(cfg, n_slots, cache_len, block, n_pages)
         compiled = cells.decode.lower(
             place(registry.abstract_params(cfg)),
             _sds(one_chip, (n_slots, 1), jnp.int32),
             _sds(one_chip, (n_slots,), jnp.int32),
-            place(jax.eval_shape(cells.init_pool)),
+            place(jax.eval_shape(cells.init_state)),
             _sds(one_chip, (n_slots, cells.max_pages), jnp.int32),
         ).compile()
     text = compiled.as_text()
@@ -217,7 +217,7 @@ def test_paged_decode_cell_compiles_with_kernel(one_chip, arch, layers,
     pool_bytes = paged.pool_geometry(cfg, n_pages, block)["pool_bytes"]
     assert mem.temp_size_in_bytes < pool_bytes / cfg.num_groups(), \
         (mem.temp_size_in_bytes, pool_bytes)
-    page_dims = jax.eval_shape(cells.init_pool)["l0"].shape[1:]
+    page_dims = jax.eval_shape(cells.init_state)["l0"].shape[1:]
     assert _pool_passes(text, page_dims) == []
     if cfg.num_experts:
         # the latent kernel and the grouped matmul run, named, and read
